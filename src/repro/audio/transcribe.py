@@ -102,7 +102,11 @@ def transcribe(samples, sr: int = 16_000, *,
     fe = frontend or FrontendConfig()
     x = resample_linear(samples, sr, fe.sample_rate)
     audio_s = len(x) / fe.sample_rate
-    if model is None or params is None:
+    if engine is not None:
+        # the reused engine's model is the truth: frames must match its
+        # width whatever arch/reduced say
+        model, params = engine.model, engine.params
+    elif model is None or params is None:
         model, params = _default_model(arch, reduced, seed)
     if not model.cfg.enc_dec:
         raise ValueError(f"transcribe needs an enc-dec (audio) model; "

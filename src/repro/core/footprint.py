@@ -100,6 +100,15 @@ MXU_LANE = 128   # last-dim tile multiple
 MXU_SUBLANE = 8  # second-minor tile multiple (f32)
 
 
+class UnsupportedCall(ValueError):
+    """A Pallas wrapper refuses a call on purpose: a shape class it was
+    not built for (cross-attention's ``sq != skv``, the speculative
+    verify's multi-query decode) or no MXU-aligned block under the VMEM
+    budget. ``kernels.api.dispatch`` sends exactly this exception to the
+    op's host chain (recorded ``accel->host``); every other error from a
+    backend propagates."""
+
+
 @dataclasses.dataclass(frozen=True)
 class BlockShape:
     bm: int
@@ -159,7 +168,7 @@ def select_blocks(m: int, n: int, k: int, budget_bytes: int,
             break
         bk = max(32, rdown(bk // 2, 32))
     if best is None:
-        raise ValueError(
+        raise UnsupportedCall(
             f"no MXU-aligned block fits budget={budget_bytes}B for "
             f"gemm ({m}x{k})@({k}x{n})")
     return best

@@ -32,6 +32,7 @@ monkeypatch ``os.environ``):
 """
 
 import os
+import pathlib
 
 BASELINE = os.environ.get("REPRO_BASELINE", "") == "1"
 
@@ -49,10 +50,7 @@ def _env_bool(name: str):
 
 def _on_tpu() -> bool:
     import jax
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def kernel_backend_override():
@@ -98,3 +96,23 @@ def allow_pallas_default() -> bool:
 def interpret_default() -> bool:
     v = _env_bool("REPRO_INTERPRET")
     return (not _on_tpu()) if v is None else v
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    no other directory is set here. Otherwise the cache lives at the
+    fixed path ``<checkout>/.jax_cache``: the directory is part of each
+    entry's key, so it never moves between runs. Entry points call this
+    (``chip_smoke.py``, the ``repro.launch`` CLIs); importing a module
+    never does."""
+    import jax
+    d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not d:
+        d = str(pathlib.Path(__file__).resolve().parents[2] / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", d)
+    # cache every program: a warm run should compile nothing
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return d

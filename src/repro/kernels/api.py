@@ -36,6 +36,7 @@ from typing import Mapping, Optional
 import jax.numpy as jnp
 
 from repro import flags
+from repro.core.footprint import UnsupportedCall
 from repro.core.workload import KernelSpec
 from repro.kernels.registry import BACKENDS, KernelOp, get_op, register
 
@@ -69,7 +70,8 @@ class DispatchContext:
 
     vmem_budget: int
     policy: str = "optimized"
-    interpret: bool = True
+    interpret: bool = dataclasses.field(
+        default_factory=flags.interpret_default)
     allow_pallas: bool = False
     force_backend: Optional[str] = None
     backends: Mapping[str, str] = dataclasses.field(default_factory=dict)
@@ -259,13 +261,14 @@ def dispatch(op_name: str, *args, ctx: Optional[DispatchContext] = None,
     decision, backend, footprint = _decide(op, spec, ctx)
     try:
         out = op.backends[backend](ctx, *args, **kwargs)
-    except ValueError:
+    except UnsupportedCall:
         if backend != "pallas" or decision == "forced":
             raise
         # the budget admitted the analytic footprint but the kernel
-        # can't take the call (no MXU-aligned block fits, or an
-        # unsupported shape class): land it on the host path, as the
-        # paper's residual machinery does.
+        # refuses the call on purpose (no MXU-aligned block fits, or a
+        # shape class it does not take): land it on the host path, as
+        # the paper's residual machinery does. Any other error — a
+        # Mosaic lowering failure included — propagates.
         backend = _first_allowed(op, op.host_order, ctx)
         out = op.backends[backend](ctx, *args, **kwargs)
         decision = "accel->host"
@@ -378,7 +381,7 @@ def _register_builtin_ops() -> None:
             # the Pallas kernel assumes square S; cross-attention
             # (sq != skv) lands on the host chunked path via dispatch's
             # accel->host fallback.
-            raise ValueError(
+            raise UnsupportedCall(
                 f"flash_attention pallas kernel requires sq == skv, got "
                 f"{q.shape[1]} vs {k.shape[1]}")
         return flash_attention(q, k, v, causal=causal, window=window,
@@ -447,7 +450,7 @@ def _register_builtin_ops() -> None:
 
     # ---- q4_decode_attention: decode matvec over the Q4_0 KV cache ----
     # Same shape/count conventions as the q8 op; the Pallas binding is
-    # single-query (speculative multi-query verify raises ValueError and
+    # single-query (speculative multi-query verify raises UnsupportedCall and
     # lands on the bf16-widened xla backend via accel->host fallback).
     register(KernelOp(
         name="q4_decode_attention",

@@ -17,12 +17,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                  scale, causal, window, softcap, n_k_blocks, bq, bk):
+                  scale, causal, window, softcap, n_k_blocks, bq, bk,
+                  kv_len):
     iq = pl.program_id(1)
     ik = pl.program_id(2)
 
@@ -43,7 +45,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     qpos = iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     kpos = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    mask = jnp.ones((bq, bk), jnp.bool_)
+    mask = kpos < kv_len                               # padded keys
     if causal:
         mask &= kpos <= qpos
     if window is not None:
@@ -70,26 +72,26 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "softcap", "bq", "bk", "interpret"))
+    "causal", "window", "softcap", "bq", "bk", "kv_len", "interpret"))
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True,
                            window: int | None = None,
                            softcap: float | None = None,
                            bq: int = 128, bk: int = 128,
+                           kv_len: int | None = None,
                            interpret: bool = False) -> jax.Array:
     """q, k, v: (BH, S, D) — heads pre-flattened (GQA handled by ops.py).
-    S must divide by bq and bk."""
+    S must divide by bq and bk. Keys at positions >= ``kv_len`` (the
+    wrapper's padding; default S) are masked out."""
     bh, s, d = q.shape
     assert k.shape == (bh, s, d) and v.shape == (bh, s, d)
     assert s % bq == 0 and s % bk == 0, (s, bq, bk)
     n_k_blocks = s // bk
     scale = 1.0 / (d ** 0.5)
-    from jax.experimental.pallas import tpu as pltpu
-
-    from repro.kernels.common import tpu_compiler_params
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, window=window,
-        softcap=softcap, n_k_blocks=n_k_blocks, bq=bq, bk=bk)
+        softcap=softcap, n_k_blocks=n_k_blocks, bq=bq, bk=bk,
+        kv_len=s if kv_len is None else kv_len)
     return pl.pallas_call(
         kernel,
         grid=(bh, s // bq, n_k_blocks),
@@ -105,7 +107,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

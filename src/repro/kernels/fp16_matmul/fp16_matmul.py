@@ -18,6 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _fp16_matmul_kernel(x_ref, w_ref, o_ref, acc_ref, *, n_k_blocks):
@@ -52,9 +53,6 @@ def fp16_matmul_pallas(x: jax.Array, w: jax.Array, *,
     assert k == k2
     assert m % bm == 0 and n % bn == 0 and k % bk == 0, ((m, n, k), (bm, bn, bk))
     n_k_blocks = k // bk
-    from jax.experimental.pallas import tpu as pltpu
-
-    from repro.kernels.common import tpu_compiler_params
     return pl.pallas_call(
         functools.partial(_fp16_matmul_kernel, n_k_blocks=n_k_blocks),
         grid=(m // bm, n // bn, n_k_blocks),
@@ -65,7 +63,7 @@ def fp16_matmul_pallas(x: jax.Array, w: jax.Array, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w)

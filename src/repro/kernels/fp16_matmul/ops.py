@@ -26,7 +26,7 @@ from repro.kernels.fp16_matmul.ref import fp16_matmul_ref
 def fp16_matmul(x: jax.Array, w: jax.Array, *,
                 vmem_budget: int = 4 * 1024 * 1024,
                 out_dtype=jnp.float32,
-                interpret: bool = True) -> jax.Array:
+                interpret: bool) -> jax.Array:
     """y = x @ w for fp16/bf16 operands of any shape; C2 split on K."""
     if x.ndim != 2:
         lead = x.shape[:-1]

@@ -4,7 +4,7 @@
 (per-token, per-head 32-blocks along head_dim). ``q4_decode_attention``
 pads S to the block multiple and dispatches the Pallas kernel; it is
 single-query only — the speculative verify's (BH, Q) case raises
-``ValueError`` so the kernel registry's accel->host fallback routes it
+``UnsupportedCall`` so the kernel registry's accel->host fallback routes it
 to the XLA backend.
 
 Traffic: the per-step cache stream drops from 2·S·D bf16 bytes to
@@ -19,6 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.footprint import UnsupportedCall
 from repro.core.quantize import QBLOCK, quantize_q4_0
 from repro.kernels.common import pad_dim
 from repro.kernels.q4_attention.q4_attention import q4_decode_attention_pallas
@@ -33,13 +34,13 @@ def quantize_kv_q4(k: jax.Array):
 
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
 def q4_decode_attention(q, kp, ks, vp, vs, length, *, bk: int = 128,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool) -> jax.Array:
     """q: (BH, 1, D); kp/vp: (BH, S, D//2) packed uint8; ks/vs scales;
     attend [0, length) with ``length`` a scalar or (BH,) vector. Handles
     S not divisible by bk via zero padding (masked by ``length``)."""
     length = jnp.asarray(length)
     if q.shape[1] != 1 or length.ndim > 1:
-        raise ValueError(
+        raise UnsupportedCall(
             "q4_decode_attention (Pallas) is single-query: got "
             f"q {q.shape}, length {length.shape}; multi-query verify "
             "routes to the XLA backend via dispatch fallback")

@@ -19,59 +19,68 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quantize import QBLOCK
+from repro.kernels.common import expand_scales, scale_operand, widen_scales
 
 NEG_INF = -1e30
 
 
-def _q4_attn_kernel(len_ref, q_ref, kp_ref, ks_ref, vp_ref, vs_ref,
-                    o_ref, m_ref, l_ref, acc_ref, *,
+def _q4_attn_kernel(len_ref, qe_ref, qo_ref, kp_ref, ks_ref, vp_ref, vs_ref,
+                    oe_ref, oo_ref, m_ref, l_ref, acce_ref, acco_ref, *,
                     scale, n_k_blocks, bk):
+    """Byte i of a packed row holds head_dim columns 2i (low nibble) and
+    2i+1 (high): q and the output are split into their even and odd
+    columns, so the nibbles are never interleaved in VMEM."""
+    h = pl.program_id(0)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0].astype(jnp.float32)                     # (1, D)
+        acce_ref[...] = jnp.zeros_like(acce_ref)
+        acco_ref[...] = jnp.zeros_like(acco_ref)
 
     def dequant(pref, sref):
-        raw = pref[0]                                    # (bk, D//2) uint8
-        lo = (raw & jnp.uint8(0xF)).astype(jnp.int8) - 8
-        hi = (raw >> 4).astype(jnp.int8) - 8
-        rows, half = raw.shape
-        codes = jnp.stack([lo, hi], axis=2).reshape(rows, 2 * half)
-        sc = sref[0].astype(jnp.float32)                 # (bk, D//32)
-        sc_full = jnp.repeat(sc, QBLOCK, axis=1)         # C1: in-VMEM
-        return codes.astype(jnp.float32) * sc_full
+        raw = pref[0].astype(jnp.int32)                  # (bk, D//2)
+        # packed columns 16g..16g+15 are head_dim columns 32g..32g+31
+        sc = expand_scales(widen_scales(sref[0]), raw.shape[1],
+                           QBLOCK // 2)                  # C1: in-VMEM
+        return (((raw & 0xF) - 8).astype(jnp.float32) * sc,
+                ((raw >> 4) - 8).astype(jnp.float32) * sc)
 
-    k = dequant(kp_ref, ks_ref)
-    v = dequant(vp_ref, vs_ref)
+    k_lo, k_hi = dequant(kp_ref, ks_ref)
+    v_lo, v_hi = dequant(vp_ref, vs_ref)
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (1, bk)
-    s = s * scale
+    qk = (((1,), (1,)), ((), ()))
+    s = (jax.lax.dot_general(qe_ref[0].astype(jnp.float32), k_lo, qk,
+                             preferred_element_type=jnp.float32)
+         + jax.lax.dot_general(qo_ref[0].astype(jnp.float32), k_hi, qk,
+                               preferred_element_type=jnp.float32))
+    s = s * scale                                        # (1, bk)
     kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-    s = jnp.where(kpos < len_ref[0, 0], s, NEG_INF)
+    s = jnp.where(kpos < len_ref[h], s, NEG_INF)
 
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
     l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    pv = (((1,), (0,)), ((), ()))
+    acce_ref[...] = acce_ref[...] * alpha + jax.lax.dot_general(
+        p, v_lo, pv, preferred_element_type=jnp.float32)
+    acco_ref[...] = acco_ref[...] * alpha + jax.lax.dot_general(
+        p, v_hi, pv, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(j == n_k_blocks - 1)
     def _done():
         l = l_ref[...]
         safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / safe).astype(o_ref.dtype)
+        oe_ref[0] = (acce_ref[...] / safe).astype(oe_ref.dtype)
+        oo_ref[0] = (acco_ref[...] / safe).astype(oo_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
@@ -89,34 +98,38 @@ def q4_decode_attention_pallas(q: jax.Array, kp: jax.Array, ks: jax.Array,
     assert ks.shape == (bh, s, d // QBLOCK), ks.shape
     n_k_blocks = s // bk
     scale = 1.0 / (d ** 0.5)
-    from jax.experimental.pallas import tpu as pltpu
-
-    from repro.kernels.common import tpu_compiler_params
     kernel = functools.partial(_q4_attn_kernel, scale=scale,
                                n_k_blocks=n_k_blocks, bk=bk)
-    grid = (bh, n_k_blocks)
     lens = jnp.broadcast_to(
         jnp.asarray(length, jnp.int32).reshape(-1), (bh,))
-    return pl.pallas_call(
+    half = d // 2
+    row = pl.BlockSpec((1, 1, half), lambda h, j, lens: (h, 0, 0))
+    # per-lane lengths ride in SMEM as a scalar-prefetch operand
+    oe, oo = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda h, j: (h, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, d), lambda h, j: (h, 0, 0)),
-            pl.BlockSpec((1, bk, d // 2), lambda h, j: (h, j, 0)),
-            pl.BlockSpec((1, bk, d // QBLOCK), lambda h, j: (h, j, 0)),
-            pl.BlockSpec((1, bk, d // 2), lambda h, j: (h, j, 0)),
-            pl.BlockSpec((1, bk, d // QBLOCK), lambda h, j: (h, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda h, j: (h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, 1, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
-        ],
-        compiler_params=tpu_compiler_params(
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, n_k_blocks),
+            in_specs=[
+                row, row,
+                pl.BlockSpec((1, bk, half), lambda h, j, lens: (h, j, 0)),
+                pl.BlockSpec((1, bk, d // QBLOCK),
+                             lambda h, j, lens: (h, j, 0)),
+                pl.BlockSpec((1, bk, half), lambda h, j, lens: (h, j, 0)),
+                pl.BlockSpec((1, bk, d // QBLOCK),
+                             lambda h, j, lens: (h, j, 0)),
+            ],
+            out_specs=[row, row],
+            scratch_shapes=[
+                pltpu.VMEM((1, 1), jnp.float32),
+                pltpu.VMEM((1, 1), jnp.float32),
+                pltpu.VMEM((1, half), jnp.float32),
+                pltpu.VMEM((1, half), jnp.float32),
+            ]),
+        out_shape=[jax.ShapeDtypeStruct((bh, 1, half), q.dtype)] * 2,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(lens.reshape(bh, 1), q, kp, ks, vp, vs)
+    )(lens, q[..., 0::2], q[..., 1::2], kp, scale_operand(ks), vp,
+      scale_operand(vs))
+    return jnp.stack([oe, oo], axis=-1).reshape(bh, 1, d)

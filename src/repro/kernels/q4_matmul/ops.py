@@ -33,7 +33,7 @@ from repro.kernels.q4_matmul.ref import q4_matmul_ref
 def q4_matmul(x: jax.Array, w: Q4Tensor, *,
               vmem_budget: int = 4 * 1024 * 1024,
               out_dtype=jnp.float32,
-              interpret: bool = True) -> jax.Array:
+              interpret: bool) -> jax.Array:
     """y = x @ dequant(w), w stored as Q4Tensor packed along K.
 
     ``w.q`` is (K//2, N) uint8 (two codes/byte), ``w.scale`` (K//QBLOCK, N).
@@ -92,9 +92,12 @@ def q4_matmul_xla(x: jax.Array, w: Q4Tensor, out_dtype=jnp.float32) -> jax.Array
     assert k == 2 * w.q.shape[0], (x.shape, w.q.shape)
     n = w.q.shape[-1]
     codes = unpack_q4(w.q, axis=0).astype(jnp.bfloat16)       # (K, N)
+    # the 32-group axis leads both operands: XLA's CPU runtime has no
+    # bf16 x bf16 -> f32 kernel for a batch dim that trails in the lhs
     xb = x.astype(jnp.bfloat16).reshape(m, k // QBLOCK, QBLOCK)
+    xb = xb.transpose(1, 0, 2)                                 # (K/32, M, 32)
     cb = codes.reshape(k // QBLOCK, QBLOCK, n)
-    part = jnp.einsum("mbk,bkn->mbn", xb, cb,
-                      preferred_element_type=jnp.float32)      # (M, K/32, N)
-    y = (part * w.scale.astype(jnp.float32)[None, :, :]).sum(axis=1)
+    part = jnp.einsum("bmk,bkn->bmn", xb, cb,
+                      preferred_element_type=jnp.float32)      # (K/32, M, N)
+    y = (part * w.scale.astype(jnp.float32)[:, None, :]).sum(axis=0)
     return y.astype(out_dtype)

@@ -20,35 +20,39 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quantize import QBLOCK
+from repro.kernels.common import scale_operand, widen_scales
 
 
-def _unpack_rows(p: jax.Array) -> jax.Array:
-    """(bk//2, bn) packed uint8 -> (bk, bn) f32 codes in [-8, 7]."""
-    lo = (p & jnp.uint8(0xF)).astype(jnp.int8) - 8
-    hi = (p >> 4).astype(jnp.int8) - 8
-    half, bn = p.shape
-    return jnp.stack([lo, hi], axis=1).reshape(2 * half, bn).astype(jnp.float32)
+def _q4_matmul_kernel(xe_ref, xo_ref, wp_ref, ws_ref, o_ref, acc_ref, *,
+                      n_k_blocks):
+    """One (bm, bn) output tile; grid dim 2 walks K in bk steps.
 
-
-def _q4_matmul_kernel(x_ref, wp_ref, ws_ref, o_ref, acc_ref, *, n_k_blocks):
-    """One (bm, bn) output tile; grid dim 2 walks K in bk steps."""
+    Packed row i holds logical rows 2i (low nibble) and 2i+1 (high), so
+    the tile is ``xe @ lo + xo @ hi`` over the even/odd columns of x:
+    the nibbles are never interleaved back into K order in VMEM."""
     k_idx = pl.program_id(2)
 
     @pl.when(k_idx == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.float32)                  # (bm, bk)
-    q = _unpack_rows(wp_ref[...])                       # (bk, bn) in VMEM (C1)
-    s = ws_ref[...].astype(jnp.float32)                 # (bk // 32, bn)
-    bk, bn = q.shape
-    scales = jnp.broadcast_to(s[:, None, :], (bk // QBLOCK, QBLOCK, bn))
-    w = q * scales.reshape(bk, bn)
-    acc_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    p = wp_ref[...].astype(jnp.int32)                   # (bk//2, bn)
+    half, bn = p.shape
+    s = widen_scales(ws_ref[...])                       # (bk // 32, bn)
+    # packed rows 16g..16g+15 are logical rows 32g..32g+31: scale group g
+    scales = jnp.broadcast_to(s[:, None, :], (s.shape[0], QBLOCK // 2, bn))
+    scales = scales.reshape(half, bn)
+    lo = ((p & 0xF) - 8).astype(jnp.float32) * scales   # C1: in VMEM
+    hi = ((p >> 4) - 8).astype(jnp.float32) * scales
+    dims = (((1,), (0,)), ((), ()))
+    acc_ref[...] += (
+        jax.lax.dot_general(xe_ref[...].astype(jnp.float32), lo, dims,
+                            preferred_element_type=jnp.float32)
+        + jax.lax.dot_general(xo_ref[...].astype(jnp.float32), hi, dims,
+                              preferred_element_type=jnp.float32))
 
     @pl.when(k_idx == n_k_blocks - 1)
     def _done():
@@ -75,21 +79,20 @@ def q4_matmul_pallas(x: jax.Array, wp: jax.Array, ws: jax.Array, *,
         (m, n, k), (bm, bn, bk))
     n_k_blocks = k // bk
     grid = (m // bm, n // bn, n_k_blocks)
-    from jax.experimental.pallas import tpu as pltpu
-
-    from repro.kernels.common import tpu_compiler_params
+    xe, xo = x[:, 0::2], x[:, 1::2]      # the nibble order, split on K
     return pl.pallas_call(
         functools.partial(_q4_matmul_kernel, n_k_blocks=n_k_blocks),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
+            pl.BlockSpec((bm, bk // 2), lambda i, j, kk: (i, kk)),
+            pl.BlockSpec((bm, bk // 2), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk // 2, bn), lambda i, j, kk: (kk, j)),
             pl.BlockSpec((bk // QBLOCK, bn), lambda i, j, kk: (kk, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, wp, ws)
+    )(xe, xo, wp, scale_operand(ws))

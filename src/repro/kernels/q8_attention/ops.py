@@ -18,6 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.footprint import UnsupportedCall
 from repro.core.quantize import QBLOCK, quantize_q8_0
 from repro.kernels.common import pad_dim
 from repro.kernels.q8_attention.q8_attention import q8_decode_attention_pallas
@@ -31,18 +32,18 @@ def quantize_kv(k: jax.Array):
 
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
 def q8_decode_attention(q, kq, ks, vq, vs, length, *, bk: int = 128,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool) -> jax.Array:
     """q: (BH, 1, D); kq/vq: (BH, S, D) int8; ks/vs scales; attend
     [0, length). ``length`` is a scalar (lockstep decode) or a (BH,)
     vector (continuous batching: every serving lane at its own depth).
     Handles S not divisible by bk via zero padding (masked by
     ``length``). Single-query only: the speculative verify's (BH, Q)
-    case raises ``ValueError`` so dispatch falls back to the XLA
+    case raises ``UnsupportedCall`` so dispatch falls back to the XLA
     backend."""
     bh, _, d = q.shape
     length = jnp.asarray(length)
     if q.shape[1] != 1 or length.ndim > 1:
-        raise ValueError(
+        raise UnsupportedCall(
             "q8_decode_attention (Pallas) is single-query: got "
             f"q {q.shape}, length {length.shape}; multi-query verify "
             "routes to the XLA backend via dispatch fallback")

@@ -19,8 +19,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quantize import QBLOCK
+from repro.kernels.common import expand_scales, scale_operand, widen_scales
 
 NEG_INF = -1e30
 
@@ -28,6 +30,7 @@ NEG_INF = -1e30
 def _q8_attn_kernel(len_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,
                     o_ref, m_ref, l_ref, acc_ref, *,
                     scale, n_k_blocks, bk):
+    h = pl.program_id(0)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -40,9 +43,7 @@ def _q8_attn_kernel(len_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,
 
     def dequant(qref, sref):
         raw = qref[0].astype(jnp.float32)                # (bk, D)
-        sc = sref[0].astype(jnp.float32)                 # (bk, D//32)
-        sc_full = jnp.repeat(sc, QBLOCK, axis=1)         # C1: in-VMEM
-        return raw * sc_full
+        return raw * expand_scales(widen_scales(sref[0]), raw.shape[1])
 
     k = dequant(kq_ref, ks_ref)
     v = dequant(vq_ref, vs_ref)
@@ -51,7 +52,7 @@ def _q8_attn_kernel(len_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,
                             preferred_element_type=jnp.float32)  # (1, bk)
     s = s * scale
     kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-    s = jnp.where(kpos < len_ref[0, 0], s, NEG_INF)
+    s = jnp.where(kpos < len_ref[h], s, NEG_INF)
 
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -86,34 +87,33 @@ def q8_decode_attention_pallas(q: jax.Array, kq: jax.Array, ks: jax.Array,
     assert ks.shape == (bh, s, d // QBLOCK), ks.shape
     n_k_blocks = s // bk
     scale = 1.0 / (d ** 0.5)
-    from jax.experimental.pallas import tpu as pltpu
-
-    from repro.kernels.common import tpu_compiler_params
     kernel = functools.partial(_q8_attn_kernel, scale=scale,
                                n_k_blocks=n_k_blocks, bk=bk)
-    grid = (bh, n_k_blocks)
     lens = jnp.broadcast_to(
         jnp.asarray(length, jnp.int32).reshape(-1), (bh,))
+    # per-lane lengths ride in SMEM as a scalar-prefetch operand
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda h, j: (h, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, d), lambda h, j: (h, 0, 0)),
-            pl.BlockSpec((1, bk, d), lambda h, j: (h, j, 0)),
-            pl.BlockSpec((1, bk, d // QBLOCK), lambda h, j: (h, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda h, j: (h, j, 0)),
-            pl.BlockSpec((1, bk, d // QBLOCK), lambda h, j: (h, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda h, j: (h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, n_k_blocks),
+            in_specs=[
+                pl.BlockSpec((1, 1, d), lambda h, j, lens: (h, 0, 0)),
+                pl.BlockSpec((1, bk, d), lambda h, j, lens: (h, j, 0)),
+                pl.BlockSpec((1, bk, d // QBLOCK),
+                             lambda h, j, lens: (h, j, 0)),
+                pl.BlockSpec((1, bk, d), lambda h, j, lens: (h, j, 0)),
+                pl.BlockSpec((1, bk, d // QBLOCK),
+                             lambda h, j, lens: (h, j, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, d), lambda h, j, lens: (h, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((1, 1), jnp.float32),
+                pltpu.VMEM((1, 1), jnp.float32),
+                pltpu.VMEM((1, d), jnp.float32),
+            ]),
         out_shape=jax.ShapeDtypeStruct((bh, 1, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
-        ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(lens.reshape(bh, 1), q, kq, ks, vq, vs)
+    )(lens, q, kq, scale_operand(ks), vq, scale_operand(vs))

@@ -29,11 +29,11 @@ from repro.kernels.q8_matmul.ref import q8_matmul_ref
 def q8_matmul(x: jax.Array, w: Q8Tensor, *,
               vmem_budget: int = 4 * 1024 * 1024,
               out_dtype=jnp.float32,
-              interpret: bool = True) -> jax.Array:
+              interpret: bool) -> jax.Array:
     """y = x @ dequant(w), w stored as Q8Tensor with shape (K, N).
 
-    ``interpret=True`` runs the kernel body on CPU (this container);
-    on real TPU pass ``interpret=False``.
+    ``interpret=True`` runs the kernel body on the CPU; on a TPU pass
+    ``interpret=False``.
     """
     if x.ndim != 2:
         lead = x.shape[:-1]

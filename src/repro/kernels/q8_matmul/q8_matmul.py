@@ -19,8 +19,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quantize import QBLOCK
+from repro.kernels.common import scale_operand, widen_scales
 
 
 def _q8_matmul_kernel(x_ref, wq_ref, ws_ref, o_ref, acc_ref, *, n_k_blocks):
@@ -33,7 +35,7 @@ def _q8_matmul_kernel(x_ref, wq_ref, ws_ref, o_ref, acc_ref, *, n_k_blocks):
 
     x = x_ref[...].astype(jnp.float32)                  # (bm, bk)
     q = wq_ref[...].astype(jnp.float32)                 # (bk, bn)
-    s = ws_ref[...].astype(jnp.float32)                 # (bk // 32, bn)
+    s = widen_scales(ws_ref[...])                       # (bk // 32, bn)
     bk, bn = q.shape
     # inline dequant: expand per-32-block scales along K (C1)
     scales = jnp.broadcast_to(s[:, None, :], (bk // QBLOCK, QBLOCK, bn))
@@ -76,18 +78,9 @@ def q8_matmul_pallas(x: jax.Array, wq: jax.Array, ws: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        scratch_shapes=[pl.ANY if False else _vmem((bm, bn), jnp.float32)],
-        compiler_params=_tpu_params(),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, wq, ws)
+    )(x, wq, scale_operand(ws))
 
-
-def _vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-    return pltpu.VMEM(shape, dtype)
-
-
-def _tpu_params():
-    from repro.kernels.common import tpu_compiler_params
-    return tpu_compiler_params(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))

@@ -18,7 +18,7 @@ from repro.kernels.slstm_scan.slstm_scan import slstm_scan_pallas
 
 @functools.partial(jax.jit, static_argnames=("t_chunk", "interpret"))
 def slstm_scan(wx: jax.Array, r_all: jax.Array, state0: jax.Array, *,
-               t_chunk: int = 64, interpret: bool = True):
+               t_chunk: int = 64, interpret: bool):
     """wx: (S, 4, B, H, hd); returns (hs (S,B,H,hd), state (4,B,H,hd))."""
     s = wx.shape[0]
     pad = (-s) % t_chunk

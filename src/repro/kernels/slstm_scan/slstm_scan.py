@@ -25,6 +25,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _slstm_chunk_kernel(wx_ref, r_ref, s0_ref, hs_ref, sout_ref, state_ref,
@@ -77,9 +78,6 @@ def slstm_scan_pallas(wx: jax.Array, r_all: jax.Array, state0: jax.Array, *,
                                                          r_all.shape)
     assert s % t_chunk == 0, (s, t_chunk)
     n_chunks = s // t_chunk
-    from jax.experimental.pallas import tpu as pltpu
-
-    from repro.kernels.common import tpu_compiler_params
     kernel = functools.partial(_slstm_chunk_kernel, t_chunk=t_chunk,
                                n_chunks=n_chunks)
     return pl.pallas_call(
@@ -99,7 +97,7 @@ def slstm_scan_pallas(wx: jax.Array, r_all: jax.Array, state0: jax.Array, *,
             jax.ShapeDtypeStruct((4, b, h, hd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((4, b, h, hd), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(wx, r_all, state0)

@@ -180,6 +180,8 @@ def main():
     ap.add_argument("--all", action="store_true",
                     help="run every (arch × shape) cell")
     args = ap.parse_args()
+    from repro import flags
+    flags.use_compile_cache()
 
     cells = []
     archs = [a for a in list_archs() if a != "whisper-tiny-en"] \

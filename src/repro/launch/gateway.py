@@ -55,6 +55,8 @@ def main(argv=None):
     ap.add_argument("--json", action="store_true",
                     help="print the full metrics summary as JSON")
     args = ap.parse_args(argv)
+    from repro import flags
+    flags.use_compile_cache()
 
     import jax
     from repro.configs import get_config, reduced

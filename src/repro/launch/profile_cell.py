@@ -23,6 +23,8 @@ def main():
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--n-micro", type=int, default=None)
     args = ap.parse_args()
+    from repro import flags
+    flags.use_compile_cache()
 
     from repro.analysis.hlo import HloAnalyzer
 

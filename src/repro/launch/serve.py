@@ -54,6 +54,8 @@ def main(argv=None):
                          "and enables the energy report")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    from repro import flags
+    flags.use_compile_cache()
 
     import jax
     import numpy as np

@@ -40,6 +40,8 @@ def main(argv=None):
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.devices}")
 
+    from repro import flags
+    flags.use_compile_cache()
     import jax
     from repro.checkpoint.store import CheckpointManager
     from repro.configs import get_config, reduced

@@ -40,6 +40,8 @@ def main(argv=None):
                          "enables the energy report")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    from repro import flags
+    flags.use_compile_cache()
 
     from repro.audio.stream import synth_waveform
     from repro.audio.transcribe import transcribe

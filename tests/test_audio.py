@@ -1,6 +1,7 @@
 """Audio pipeline: golden log-mel frontend, streaming exactness, and the
 end-to-end transcribe API."""
 
+import dataclasses
 import functools
 
 import jax
@@ -289,6 +290,22 @@ def test_transcribe_engine_reuse_reports_per_call_stats():
         transcribe(wave, 16_000, model=model, params=params,
                    chunk_frames=8, max_new=4, engine=a.engine,
                    platform="rtx-4090")
+
+
+def test_transcribe_reused_engine_brings_its_model():
+    """engine= alone serves with the engine's own model: the frames are
+    built at its width, not at the default (reduced) arch's."""
+    cfg = dataclasses.replace(reduced(get_config("whisper-tiny-en")),
+                              d_model=64)
+    model = build(cfg)
+    params = model.init_values(jax.random.key(0))
+    wave = synth_waveform(0.3)
+    a = transcribe(wave, 16_000, model=model, params=params,
+                   chunk_frames=8, max_new=4)
+    for stream in (False, True):
+        b = transcribe(wave, 16_000, chunk_frames=8, max_new=4,
+                       engine=a.engine, stream=stream)
+        assert b.tokens == a.tokens
 
 
 def test_transcribe_rejects_non_enc_dec_and_empty_audio():

@@ -29,6 +29,7 @@ import pytest
 from repro.configs import get_config, reduced
 from repro.models.model import build
 from repro.serving.engine import AudioRequest, Request, ServeEngine
+from repro.serving.reference import assert_greedy_matches, tie_margin
 from repro.serving.scheduler import BatchScheduler
 
 ARCHS = ("qwen3-4b", "whisper-tiny-en", "qwen3-moe-30b-a3b",
@@ -41,11 +42,6 @@ PAIRS = [(a, "bf16") for a in ARCHS] + [(a, "q8_0") for a in Q8_ARCHS]
 
 PROMPTS = ([5, 6, 7], [9, 10, 11, 12])
 MAX_NEW = 6
-
-# see tests/test_serving.py: greedy picks may flip at near-ties under
-# bf16 accumulation-order differences
-_TIE_MARGIN = {"bf16": 0.15, "f16": 0.05}
-_TIE_MARGIN_DEFAULT = 1e-3
 
 _SETUP_CACHE: dict = {}
 
@@ -95,42 +91,6 @@ def _request(cfg, uid, tokens, max_new=MAX_NEW, eos=-2, fuid=None):
                    eos_id=eos)
 
 
-def _ref_logits(model, params, toks, frames):
-    batch = {"tokens": jnp.asarray([toks])}
-    if frames is not None:
-        batch["enc_frames"] = jnp.asarray(frames)[None]
-    logits, _ = model.forward(params, batch, mode="train")
-    return np.asarray(logits[0, -1], np.float32)
-
-
-def _greedy_ref(model, params, prompt, frames, n_new):
-    toks, out = list(prompt), []
-    for _ in range(n_new):
-        nxt = int(_ref_logits(model, params, toks, frames).argmax())
-        out.append(nxt)
-        toks.append(nxt)
-    return out
-
-
-def _assert_matches_ref(model, params, prompt, frames, got, margin):
-    """Engine tokens == slot-free greedy reference, except the first
-    divergence must be a near-tie (reference logit of the engine's
-    pick within ``margin`` of the reference argmax); comparison stops
-    at a tie flip — the sequences legitimately differ after it."""
-    toks = list(prompt)
-    for i, tok in enumerate(got):
-        lg = _ref_logits(model, params, toks, frames)
-        want = int(lg.argmax())
-        if tok == want:
-            toks.append(tok)
-            continue
-        gap = float(lg[want] - lg[tok])
-        assert gap < margin, (
-            f"engine diverged at step {i} ({tok} vs {want}) with a "
-            f"non-tie logit gap {gap:.4f} >= {margin}")
-        return
-
-
 def _drain(eng):
     while eng.n_active:
         eng.step()
@@ -143,7 +103,7 @@ def _drain(eng):
                          ids=[f"{a}|{d}" for a, d in PAIRS])
 def test_conformance_battery(arch, cache_dtype):
     cfg, model, params, eng = _engine(arch, cache_dtype=cache_dtype)
-    margin = _TIE_MARGIN.get(cfg.dtype, _TIE_MARGIN_DEFAULT)
+    margin = tie_margin(cfg)
 
     # --- admit -> prefill -> fused decode -> drain -------------------
     sts = [eng.admit(_request(cfg, i, p)) for i, p in enumerate(PROMPTS)]
@@ -160,7 +120,8 @@ def test_conformance_battery(arch, cache_dtype):
     # near-tie envelope on these workloads)
     for st, p in zip(sts, PROMPTS):
         frames = _frames(cfg, st.req.uid) if cfg.enc_dec else None
-        _assert_matches_ref(model, params, p, frames, st.out, margin)
+        assert_greedy_matches(model, params, p, st.out, margin,
+                              enc_frames=frames)
 
     # --- fused tick == sequential single steps -----------------------
     *_, eng_seq = _engine(arch, cache_dtype=cache_dtype)

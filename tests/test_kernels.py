@@ -286,3 +286,74 @@ def test_q8_decode_attention_close_to_exact():
     dense = jnp.einsum("bqk,bkd->bqd", jax.nn.softmax(sd, -1), v)
     rel = float(jnp.linalg.norm(got - dense) / jnp.linalg.norm(dense))
     assert rel < 0.02, rel
+
+
+# ------------------------------------------- padded flash / q4 kernels
+
+@pytest.mark.parametrize("s,causal", [(200, False), (200, True), (12, False)])
+def test_flash_attention_wrapper_pads_ragged_seq(s, causal):
+    """S that no power-of-two block divides (a 30 s Whisper window is
+    1500 frames) pads to 128-row blocks; padded keys never leak into
+    the softmax and padded query rows are sliced off."""
+    b, h, d = 1, 2, 64
+    q = jax.random.normal(jax.random.fold_in(KEY, 61), (b, s, h, d))
+    k = jax.random.normal(jax.random.fold_in(KEY, 62), (b, s, h, d))
+    v = jax.random.normal(jax.random.fold_in(KEY, 63), (b, s, h, d))
+    got = flash_attention(q, k, v, causal=causal, interpret=True)
+    flat = [t.transpose(0, 2, 1, 3).reshape(b * h, s, d) for t in (q, k, v)]
+    want = attention_ref(*flat, causal=causal).reshape(
+        b, h, s, d).transpose(0, 2, 1, 3)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)
+
+
+def test_widen_scales_decodes_every_f16_bit_pattern():
+    """f16 scales enter the Pallas kernels as int16 bits (Mosaic cannot
+    load f16 on the TPU); the in-kernel decode is exact for all 65536
+    patterns, subnormals, signed zeros and inf/nan included."""
+    from repro.kernels.common import scale_operand, widen_scales
+    bits = np.arange(65536, dtype=np.uint32).astype(np.uint16)
+    f16 = bits.view(np.float16)
+    got = np.asarray(widen_scales(scale_operand(jnp.asarray(f16))))
+    want = f16.astype(np.float32)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(np.signbit(got), np.signbit(want))
+
+
+@pytest.mark.parametrize("m,n,k", [(8, 128, 64), (5, 130, 96), (1, 256, 512)])
+def test_q4_matmul_matches_ref(m, n, k):
+    """Nibble-unpack-in-kernel Q4_0 GEMM (even/odd K split) == oracle,
+    ragged M/N and the C2 residual included."""
+    from repro.core.quantize import quantize_q4_0
+    from repro.kernels.q4_matmul.ops import q4_matmul
+    from repro.kernels.q4_matmul.ref import q4_matmul_ref
+    x = jax.random.normal(jax.random.fold_in(KEY, m + 70), (m, k))
+    w = jax.random.normal(jax.random.fold_in(KEY, n + 71), (k, n))
+    wq = quantize_q4_0(w, axis=0)
+    got = q4_matmul(x, wq, interpret=True)
+    want = q4_matmul_ref(x, wq.q, wq.scale)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-3)
+
+
+@pytest.mark.parametrize("bh,s,d,length,bk", [
+    (4, 256, 64, 200, 128),       # masked tail
+    (2, 300, 32, 300, 128),       # ragged S -> padded blocks
+])
+def test_q4_decode_attention_matches_ref(bh, s, d, length, bk):
+    """Nibble-packed Q4_0 KV attention (even/odd head_dim split, per-lane
+    lengths in SMEM) == the dequantized dense oracle."""
+    from repro.kernels.q4_attention.ops import (q4_decode_attention,
+                                                quantize_kv_q4)
+    from repro.kernels.q4_attention.ref import q4_decode_attention_ref
+    q = jax.random.normal(jax.random.fold_in(KEY, bh + 80), (bh, 1, d))
+    k = jax.random.normal(jax.random.fold_in(KEY, s + 81), (bh, s, d))
+    v = jax.random.normal(jax.random.fold_in(KEY, d + 82), (bh, s, d))
+    kp, ks = quantize_kv_q4(k)
+    vp, vs = quantize_kv_q4(v)
+    lens = jnp.full((bh,), length, jnp.int32).at[0].set(1)
+    got = q4_decode_attention(q, kp, ks, vp, vs, lens, bk=bk,
+                              interpret=True)
+    want = q4_decode_attention_ref(q, kp, ks, vp, vs, lens)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)

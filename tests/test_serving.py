@@ -10,6 +10,8 @@ from repro.kernels.api import reset_dispatch_log
 from repro.models.model import build
 from repro.serving.engine import (AudioRequest, Request, ServeEngine,
                                   _bucket)
+from repro.serving.reference import (assert_greedy_matches,
+                                     greedy_reference, tie_margin)
 from repro.serving.scheduler import BatchScheduler
 
 
@@ -21,64 +23,18 @@ def _engine(arch="qwen3-4b", n_slots=4, max_len=96, seed=0, **kw):
                                            max_len=max_len, **kw)
 
 
-def _greedy_reference(model, params, prompt, n_new):
-    """Slot-free reference: full forward re-run per token (greedy)."""
-    toks = list(prompt)
-    out = []
-    for _ in range(n_new):
-        logits, _ = model.forward(params,
-                                  {"tokens": jnp.asarray([toks])},
-                                  mode="train")
-        nxt = int(jnp.argmax(logits[0, -1]))
-        out.append(nxt)
-        toks.append(nxt)
-    return out
-
-
-# logit margin under which a greedy pick may legitimately flip between
-# the engine's decode path and the full-forward reference (accumulation
-# order differs; bf16 activations round ~1e-2-scale logit differences).
-# Keyed by the config's *compute* dtype — params are stored f32.
-_TIE_MARGIN = {"bf16": 0.15, "f16": 0.05}
-_TIE_MARGIN_DEFAULT = 1e-3
-
-
-def _assert_greedy_matches(model, params, prompt, got, margin):
-    """Engine tokens must equal the slot-free greedy reference, except
-    that at the FIRST divergence the engine's pick must be a near-tie:
-    its reference logit within ``margin`` of the reference argmax. After
-    a tie flip the sequences legitimately differ, so comparison stops
-    there (the prefix equality is still asserted)."""
-    toks = list(prompt)
-    for i, tok in enumerate(got):
-        logits, _ = model.forward(params,
-                                  {"tokens": jnp.asarray([toks])},
-                                  mode="train")
-        lg = np.asarray(logits[0, -1], np.float32)
-        want = int(lg.argmax())
-        if tok == want:
-            toks.append(tok)
-            continue
-        gap = float(lg[want] - lg[tok])
-        assert gap < margin, (
-            f"engine diverged at step {i} ({tok} vs {want}) with a "
-            f"non-tie logit gap {gap:.4f} >= {margin}")
-        return
-    # fully identical sequences
-
-
 def test_engine_matches_slotfree_reference():
     """Tokens from the batched continuous engine == full-forward greedy,
     up to near-ties at the bf16 rounding boundary (per-dtype margin)."""
     cfg, model, params, eng = _engine()
-    margin = _TIE_MARGIN.get(cfg.dtype, _TIE_MARGIN_DEFAULT)
+    margin = tie_margin(cfg)
     prompts = [[5, 6, 7, 8], [9, 10, 11], [3, 4, 5, 6, 7, 8, 9]]
     sts = [eng.admit(Request(uid=i, tokens=p, max_new=4, eos_id=-2))
            for i, p in enumerate(prompts)]
     while eng.n_active:
         eng.step()
     for st, p in zip(sts, prompts):
-        _assert_greedy_matches(model, params, p, st.out, margin)
+        assert_greedy_matches(model, params, p, st.out, margin)
 
 
 def test_interleaved_admission_does_not_corrupt():
@@ -104,7 +60,7 @@ def test_interleaved_admission_does_not_corrupt():
 def test_eos_stops_early():
     cfg, model, params, eng = _engine()
     eng.admit(Request(uid=0, tokens=[5, 6, 7], max_new=50, eos_id=-2))
-    want = _greedy_reference(model, params, [5, 6, 7], 3)
+    want = greedy_reference(model, params, [5, 6, 7], 3)
     eos = want[1]
     st2 = eng.admit(Request(uid=1, tokens=[5, 6, 7], max_new=50, eos_id=eos))
     while eng.n_active:
@@ -156,21 +112,6 @@ def _whisper_frames(cfg, rng, lens=(8, 12, 8)):
             for n in lens]
 
 
-def _greedy_encdec_reference(model, params, prompt, frames, n_new):
-    """Slot-free enc-dec reference: full forward re-run per token."""
-    toks = list(prompt)
-    out = []
-    fr = jnp.asarray(frames)[None]
-    for _ in range(n_new):
-        logits, _ = model.forward(
-            params, {"tokens": jnp.asarray([toks]), "enc_frames": fr},
-            mode="train")
-        nxt = int(jnp.argmax(logits[0, -1]))
-        out.append(nxt)
-        toks.append(nxt)
-    return out
-
-
 def _run_whisper_engine(cache_dtype, frames, n_new=4):
     cfg, model, params, eng = _engine("whisper-tiny-en", n_slots=4,
                                       max_len=64, enc_len=16,
@@ -193,7 +134,7 @@ def test_whisper_engine_matches_slotfree_reference():
     frames = _whisper_frames(cfg0, rng)
     cfg, model, params, eng, sts = _run_whisper_engine("bf16", frames)
     for st, p, f in zip(sts, WHISPER_PROMPTS, frames):
-        want = _greedy_encdec_reference(model, params, p, f, 4)
+        want = greedy_reference(model, params, p, 4, enc_frames=f)
         assert st.out == want, (st.out, want)
 
 
