@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.kernels.api import dispatch
 
 SAMPLE_RATE = 16_000
@@ -198,8 +199,11 @@ def mel_to_frames(logmel, d_model: int,
 def audio_frames(samples, d_model: int,
                  cfg: FrontendConfig = FrontendConfig()) -> jnp.ndarray:
     """samples -> (n_embed_frames, d_model) encoder frame embeddings:
-    the full frontend (``log_mel`` then ``mel_to_frames``)."""
-    return mel_to_frames(log_mel(samples, cfg), d_model, cfg)
+    the full frontend (``log_mel`` then ``mel_to_frames``). Its span,
+    ``frontend.frames``, is the host time of the call: it dispatches the
+    frontend's operations and does not wait for the device."""
+    with tracing.span("frontend.frames"):
+        return mel_to_frames(log_mel(samples, cfg), d_model, cfg)
 
 
 def resample_linear(samples, sr_in: int, sr_out: int) -> np.ndarray:

@@ -46,6 +46,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from repro import tracing
 from repro.gateway.metrics import GatewayMetrics, RequestRecord
 from repro.gateway.slo import (BATCH, INTERACTIVE, STANDARD,
                                AdmissionQueue, SLOClass)
@@ -519,12 +520,22 @@ class Gateway:
 
     async def _run(self) -> None:
         loop = asyncio.get_running_loop()
+        tick = wait = None
         try:
             while True:
-                self._feed_streams()
-                self._prefill_selected()
+                # a tick: an iteration that starts with a lane decoding
+                # or an admission picked (so it dispatches, unless that
+                # request is shed first); its time not under a child
+                # span is time the loop gave to clients
+                tick = tracing.begin("gateway.tick") \
+                    if self.engine.active or self._selected else None
+                with tracing.span("gateway.feed_streams"):
+                    self._feed_streams()
+                with tracing.span("gateway.admit"):
+                    self._prefill_selected()
                 pending = self.engine.step_begin()
                 if pending is None:
+                    tracing.end(tick)
                     # no lane decoding: admit immediately, else sleep
                     # until a submit/feed wakes us (bounded, so paused
                     # streams and close() are re-checked)
@@ -547,21 +558,30 @@ class Gateway:
                 # ---- overlap window: the device is running this tick.
                 # Pick next tick's admissions, shed expired work, and
                 # yield so client coroutines submit/cancel/feed.
-                self._select_admissions()
+                with tracing.span("gateway.select"):
+                    self._select_admissions()
                 await asyncio.sleep(0)
                 # THE host sync — in an executor so the event loop (and
-                # every client) stays live during the device wait.
+                # every client) stays live during the device wait. The
+                # wait outlasts the fetch by the time a finished tick
+                # waited for the loop.
+                wait = tracing.begin("gateway.device_wait")
                 tok_blk, emit_blk = await loop.run_in_executor(
-                    None, self.engine.step_fetch, pending)
+                    None, tracing.carry(self.engine.step_fetch), pending)
+                tracing.end(wait)
                 finished = self.engine.step_replay(pending, tok_blk,
                                                    emit_blk)
                 self._tick_ema = self._ema(self._tick_ema,
                                            self._now() - t0)
                 self.metrics.ticks += 1
-                for st in finished:
-                    self._complete(st)
+                with tracing.span("gateway.complete"):
+                    for st in finished:
+                        self._complete(st)
                 await asyncio.sleep(0)     # let clients see results
+                tracing.end(tick)
         finally:
+            tracing.end(wait)
+            tracing.end(tick)
             self.metrics.stopped_t = self._now()
 
     def _abort_unfinalized(self) -> None:

@@ -86,7 +86,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import flags
+from repro import flags, tracing
 from repro.core.quantize import (Q4Tensor, Q8Tensor, quantize_q4_0,
                                  quantize_q8_0, quantize_tree,
                                  stored_bytes)
@@ -896,92 +896,99 @@ class ServeEngine:
                 f"open_stream/stream_feed (or BatchScheduler.submit)")
         if not self.free:
             return None
-        err = self.validate(req)
-        if err is not None:
-            raise RejectionError(err)
-        n = len(req.tokens)
-        slot = self.free.pop()
-        # recurrent lanes (LaneStateSpec.prefill_exact) fold every input
-        # position into the end-of-prompt state, so bucket zero-padding
-        # would corrupt it — prefill at the exact prompt length (one
-        # compile per distinct length; attention-only lanes keep the
-        # power-of-2 bucket grid)
-        bucket = n if self.spec.prefill_exact \
-            else min(_bucket(n), self.max_len)
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :n] = req.tokens
-        enc_s = None
-        # resolve the encoder input host-side first: the paged path
-        # needs enc_s (and the content digest) before any page moves
-        states = frames = None
-        if self.enc_dec and req.enc_states is not None:
-            # precomputed encoder states (chunked/streaming encode):
-            # prefill skips the encoder pass entirely.
-            states = jnp.asarray(req.enc_states)[None]
-            enc_s = int(states.shape[1])
-        elif self.enc_dec:
-            # encode at the exact frame count: the encoder attends
-            # bidirectionally, so bucket padding would corrupt every
-            # frame state (one compile per distinct enc_s).
-            frames = jnp.asarray(np.asarray(req.enc_frames),
-                                 jnp.float32)[None]
-            enc_s = int(frames.shape[1])
-        pv_self = pv_cross = None
-        if self.paged:
-            from_states = req.enc_states is not None
-            digest = _enc_digest(
-                req.enc_states if from_states else req.enc_frames,
-                "states" if from_states else "frames")
-            try:
-                self.pages.admit_lane(
-                    slot, req.tokens, digest,
-                    max_new=req.max_new + (self.spec_k - 1
-                                           if self.spec_k else 0),
-                    enc_s=enc_s)
-            except PageAllocError:
-                # transient: pages drain as lanes finish — same retry
-                # contract as a full slot pool (scheduler re-queues)
-                self.free.append(slot)
-                return None
-            pv_self = jnp.asarray(self.pages.self_table.row(slot),
-                                  jnp.int32)
-            pv_cross = jnp.asarray(self.pages.cross_table.row(slot),
-                                   jnp.int32)
-        with use_context(self.dispatch_ctx), _quiet_donation():
-            if self.paged:
-                fn = self._prefill_fn(bucket, enc_s,
-                                      from_states=states is not None)
-                first, self.cache = fn(
-                    self.params, self.cache, jnp.asarray(toks), n,
-                    pv_self, pv_cross,
-                    states if states is not None else frames)
-            elif states is not None:
-                first, self.cache = self._prefill_fn(
-                    bucket, enc_s, from_states=True)(
+        with tracing.span("engine.admit") as sp:
+            # host preparation: prompt, frames to the device, pages
+            with tracing.span("engine.admit.inputs"):
+                err = self.validate(req)
+                if err is not None:
+                    raise RejectionError(err)
+                n = len(req.tokens)
+                slot = self.free.pop()
+                # recurrent lanes (LaneStateSpec.prefill_exact) fold every
+                # input position into the end-of-prompt state, so bucket
+                # zero-padding would corrupt it — prefill at the exact
+                # prompt length (one compile per distinct length;
+                # attention-only lanes keep the power-of-2 bucket grid)
+                bucket = n if self.spec.prefill_exact \
+                    else min(_bucket(n), self.max_len)
+                toks = np.zeros((1, bucket), np.int32)
+                toks[0, :n] = req.tokens
+                enc_s = None
+                # resolve the encoder input host-side first: the paged path
+                # needs enc_s (and the content digest) before any page moves
+                states = frames = None
+                if self.enc_dec and req.enc_states is not None:
+                    # precomputed encoder states (chunked/streaming encode):
+                    # prefill skips the encoder pass entirely.
+                    states = jnp.asarray(req.enc_states)[None]
+                    enc_s = int(states.shape[1])
+                elif self.enc_dec:
+                    # encode at the exact frame count: the encoder attends
+                    # bidirectionally, so bucket padding would corrupt every
+                    # frame state (one compile per distinct enc_s).
+                    frames = jnp.asarray(np.asarray(req.enc_frames),
+                                         jnp.float32)[None]
+                    enc_s = int(frames.shape[1])
+                pv_self = pv_cross = None
+                if self.paged:
+                    from_states = req.enc_states is not None
+                    digest = _enc_digest(
+                        req.enc_states if from_states else req.enc_frames,
+                        "states" if from_states else "frames")
+                    try:
+                        self.pages.admit_lane(
+                            slot, req.tokens, digest,
+                            max_new=req.max_new + (self.spec_k - 1
+                                                   if self.spec_k else 0),
+                            enc_s=enc_s)
+                    except PageAllocError:
+                        # transient: pages drain as lanes finish — same retry
+                        # contract as a full slot pool (scheduler re-queues)
+                        self.free.append(slot)
+                        return None
+                    pv_self = jnp.asarray(self.pages.self_table.row(slot),
+                                          jnp.int32)
+                    pv_cross = jnp.asarray(self.pages.cross_table.row(slot),
+                                           jnp.int32)
+            if sp is not None:
+                sp.attrs.update(uid=req.uid, bucket=bucket, enc_s=enc_s)
+            with tracing.span("engine.prefill"), \
+                    use_context(self.dispatch_ctx), _quiet_donation():
+                if self.paged:
+                    fn = self._prefill_fn(bucket, enc_s,
+                                          from_states=states is not None)
+                    first, self.cache = fn(
                         self.params, self.cache, jnp.asarray(toks), n,
-                        slot, states)
-            elif self.enc_dec:
-                first, self.cache = self._prefill_fn(bucket, enc_s)(
-                    self.params, self.cache, jnp.asarray(toks), n, slot,
-                    frames)
+                        pv_self, pv_cross,
+                        states if states is not None else frames)
+                elif states is not None:
+                    first, self.cache = self._prefill_fn(
+                        bucket, enc_s, from_states=True)(
+                            self.params, self.cache, jnp.asarray(toks), n,
+                            slot, states)
+                elif self.enc_dec:
+                    first, self.cache = self._prefill_fn(bucket, enc_s)(
+                        self.params, self.cache, jnp.asarray(toks), n, slot,
+                        frames)
+                else:
+                    first, self.cache = self._prefill_fn(bucket)(
+                        self.params, self.cache, jnp.asarray(toks), n, slot)
+            with tracing.span("engine.first_token"):
+                first = int(first)   # scalar fetch: the only admit sync
+            self._generated += 1
+            self.lanestate.reserve(slot, self.spec, n_tokens=n + req.max_new,
+                                   enc_frames=enc_s or 0)
+            st = RequestState(req=req, slot=slot, pos=n, out=[first])
+            done = first == req.eos_id or len(st.out) >= req.max_new
+            self._set_lane(slot, token=first, pos=n, enc_len=enc_s or 0,
+                           eos=req.eos_id, max_new=req.max_new, n_out=1,
+                           active=not done)
+            if done:
+                st.done = True
+                self._free_slot(slot)
             else:
-                first, self.cache = self._prefill_fn(bucket)(
-                    self.params, self.cache, jnp.asarray(toks), n, slot)
-        first = int(first)   # scalar fetch — the only admit-time sync
-        self._generated += 1
-        self.lanestate.reserve(slot, self.spec, n_tokens=n + req.max_new,
-                               enc_frames=enc_s or 0)
-        st = RequestState(req=req, slot=slot, pos=n, out=[first])
-        done = first == req.eos_id or len(st.out) >= req.max_new
-        self._set_lane(slot, token=first, pos=n, enc_len=enc_s or 0,
-                       eos=req.eos_id, max_new=req.max_new, n_out=1,
-                       active=not done)
-        if done:
-            st.done = True
-            self._free_slot(slot)
-        else:
-            self.active[slot] = st
-        return st
+                self.active[slot] = st
+            return st
 
     # ---------------------------------------------------- streaming audio
     def open_stream(self, req: StreamingAudioRequest
@@ -1016,53 +1023,54 @@ class ServeEngine:
         K/V in place, and grow the lane's ``enc_lens`` so the very next
         decode tick attends the new audio. Appends a partial-hypothesis
         snapshot to ``st.partials``."""
-        slot = st.slot
-        ss = self._streams[slot]
-        fr = jnp.asarray(np.asarray(frames, np.float32))[None]
-        s_new = int(fr.shape[1])
-        if ss.n_frames + s_new > self.enc_len:
-            raise RejectionError(Rejection(
-                RejectCode.ENC_OVERFLOW,
-                f"request {st.req.uid}: stream overflows the pool "
-                f"enc_len {self.enc_len} ({ss.n_frames}+{s_new})"))
-        with use_context(self.dispatch_ctx):
-            states = self._encode(self.params, fr)
-        ss.states.append(states)
-        first_feed = not ss.anchored
-        if self.paged:
-            # grow the lane's cross pages to cover the new chunk before
-            # anything writes it (the first feed's pages are written by
-            # the anchor prefill, later feeds by the extend jit)
-            try:
-                phys, off = self.pages.extend_cross(slot, ss.n_frames,
-                                                    s_new)
-            except PageAllocError as e:
+        with tracing.span("engine.stream_feed"):
+            slot = st.slot
+            ss = self._streams[slot]
+            fr = jnp.asarray(np.asarray(frames, np.float32))[None]
+            s_new = int(fr.shape[1])
+            if ss.n_frames + s_new > self.enc_len:
                 raise RejectionError(Rejection(
-                    RejectCode.POOL_EXHAUSTED,
-                    f"request {st.req.uid}: cross-KV page pool "
-                    f"exhausted mid-stream ({e})"))
-        if not first_feed:
-            # incremental extension: project the new states through each
-            # decoder layer's cross K/V and write them after the
-            # already-cached positions (quantizing for a q8_0 pool; the
-            # pool buffer is donated — an in-place plane write).
-            with use_context(self.dispatch_ctx), _quiet_donation():
-                k, v = self._cross_kv(self.params, states)
-                if self.paged:
-                    self.cache = self._extend(
-                        self.cache, k, v, jnp.asarray(phys, jnp.int32),
-                        jnp.asarray(off, jnp.int32))
-                else:
-                    self.cache = self._extend(self.cache, k, v, slot,
-                                              ss.n_frames)
-        ss.n_frames += s_new
-        self.lanestate.extend_cross(slot, s_new)
-        if first_feed:
-            self._anchor(st, ss, final=False)
-        else:
-            self._enc_lens = self._enc_lens.at[slot].set(ss.n_frames)
-        st.partials.append(list(st.out))
-        return st
+                    RejectCode.ENC_OVERFLOW,
+                    f"request {st.req.uid}: stream overflows the pool "
+                    f"enc_len {self.enc_len} ({ss.n_frames}+{s_new})"))
+            with use_context(self.dispatch_ctx):
+                states = self._encode(self.params, fr)
+            ss.states.append(states)
+            first_feed = not ss.anchored
+            if self.paged:
+                # grow the lane's cross pages to cover the new chunk before
+                # anything writes it (the first feed's pages are written by
+                # the anchor prefill, later feeds by the extend jit)
+                try:
+                    phys, off = self.pages.extend_cross(slot, ss.n_frames,
+                                                        s_new)
+                except PageAllocError as e:
+                    raise RejectionError(Rejection(
+                        RejectCode.POOL_EXHAUSTED,
+                        f"request {st.req.uid}: cross-KV page pool "
+                        f"exhausted mid-stream ({e})"))
+            if not first_feed:
+                # incremental extension: project the new states through each
+                # decoder layer's cross K/V and write them after the
+                # already-cached positions (quantizing for a q8_0 pool; the
+                # pool buffer is donated — an in-place plane write).
+                with use_context(self.dispatch_ctx), _quiet_donation():
+                    k, v = self._cross_kv(self.params, states)
+                    if self.paged:
+                        self.cache = self._extend(
+                            self.cache, k, v, jnp.asarray(phys, jnp.int32),
+                            jnp.asarray(off, jnp.int32))
+                    else:
+                        self.cache = self._extend(self.cache, k, v, slot,
+                                                  ss.n_frames)
+            ss.n_frames += s_new
+            self.lanestate.extend_cross(slot, s_new)
+            if first_feed:
+                self._anchor(st, ss, final=False)
+            else:
+                self._enc_lens = self._enc_lens.at[slot].set(ss.n_frames)
+            st.partials.append(list(st.out))
+            return st
 
     def stream_finalize(self, st: RequestState) -> RequestState:
         """End of audio: re-anchor the prompt against the *full* encoder
@@ -1177,8 +1185,11 @@ class ServeEngine:
         if self.spec_k and k % self.spec_k:
             raise ValueError(f"decode block ({k}) must be a multiple of "
                              f"spec_k ({self.spec_k})")
-        fn = self._decode_fn(k)
-        with use_context(self.dispatch_ctx), _quiet_donation():
+        with tracing.span("engine.dispatch") as sp, \
+                use_context(self.dispatch_ctx), _quiet_donation():
+            if sp is not None:
+                sp.attrs.update(lanes=len(self.active), k=k)
+            fn = self._decode_fn(k)
             if self.paged:
                 # the tick donates the device tables and returns them
                 # aliased (it never remaps pages); re-adopt them guarded
@@ -1208,28 +1219,29 @@ class ServeEngine:
         fetch the ``(k, n_slots)`` token block + emit mask in one
         device_get. Safe to call off-thread (the gateway fetches in an
         executor so its event loop stays live during the device wait)."""
-        tok_blk, emit_blk = jax.device_get(
-            (pending.tok_blk, pending.emit_blk))
-        self._host_syncs += 1
-        self._ticks += 1
-        emitted = int(emit_blk.sum())
-        self._generated += emitted
-        if self.spec_k:
-            # a spec tick executes rounds, not plain steps: each round
-            # is spec_k - 1 draft forwards + ONE multi-query verify
-            # forward of the full model
-            rounds = pending.k // self.spec_k
-            self._spec_rounds += rounds
-            self._draft_steps += rounds * (self.spec_k - 1)
-            self._verify_steps += rounds
-            self._spec_emitted += emitted
-            # (round, lane) pairs that emitted at all — the denominator
-            # of the draft-acceptance rate
-            live = emit_blk.reshape(rounds, self.spec_k, -1).any(axis=1)
-            self._spec_live_rounds += int(live.sum())
-        else:
-            self._decode_steps += pending.k
-        return tok_blk, emit_blk
+        with tracing.span("engine.fetch"):
+            tok_blk, emit_blk = jax.device_get(
+                (pending.tok_blk, pending.emit_blk))
+            self._host_syncs += 1
+            self._ticks += 1
+            emitted = int(emit_blk.sum())
+            self._generated += emitted
+            if self.spec_k:
+                # a spec tick executes rounds, not plain steps: each round
+                # is spec_k - 1 draft forwards + ONE multi-query verify
+                # forward of the full model
+                rounds = pending.k // self.spec_k
+                self._spec_rounds += rounds
+                self._draft_steps += rounds * (self.spec_k - 1)
+                self._verify_steps += rounds
+                self._spec_emitted += emitted
+                # (round, lane) pairs that emitted at all — the denominator
+                # of the draft-acceptance rate
+                live = emit_blk.reshape(rounds, self.spec_k, -1).any(axis=1)
+                self._spec_live_rounds += int(live.sum())
+            else:
+                self._decode_steps += pending.k
+            return tok_blk, emit_blk
 
     @property
     def acceptance_rate(self) -> float:
@@ -1247,40 +1259,41 @@ class ServeEngine:
         """Host replay of a fetched tick: append emitted tokens to each
         lane's ``RequestState``, free finished slots, pause streaming
         lanes — the bookkeeping no jit can do."""
-        k = pending.k
-        finished = []
-        for slot, st in list(self.active.items()):
-            for j in range(k):
-                if not emit_blk[j, slot]:
-                    # plain ticks freeze lanes prefix-contiguously, but
-                    # a speculative round that accepts m < spec_k tokens
-                    # leaves a gap before the next round's rows — keep
-                    # scanning the whole block
-                    continue
-                tok = int(tok_blk[j, slot])
-                st.out.append(tok)
-                st.pos += 1
-                # replay of the on-device stop condition, token for token
-                if tok == st.req.eos_id or len(st.out) >= st.req.max_new \
-                        or st.pos >= self.max_len - 1:
-                    if slot in self._streams:
-                        # mid-stream hypothesis complete: pause the lane
-                        # (keep the slot and its growing encoder cache);
-                        # stream_finalize re-anchors and decodes the
-                        # final transcript.
-                        self.active.pop(slot)
-                    else:
-                        st.done = True
-                        self.active.pop(slot)
-                        self._free_slot(slot)
-                        finished.append(st)
-                    break
-            if self.paged:
-                # advance the lane's valid-token extent (fragmentation
-                # accounting only; allocation already covered max_new;
-                # no-op for lanes freed above)
-                self.pages.note_len(slot, st.pos)
-        return finished
+        with tracing.span("engine.replay"):
+            k = pending.k
+            finished = []
+            for slot, st in list(self.active.items()):
+                for j in range(k):
+                    if not emit_blk[j, slot]:
+                        # plain ticks freeze lanes prefix-contiguously, but
+                        # a speculative round that accepts m < spec_k tokens
+                        # leaves a gap before the next round's rows — keep
+                        # scanning the whole block
+                        continue
+                    tok = int(tok_blk[j, slot])
+                    st.out.append(tok)
+                    st.pos += 1
+                    # replay of the on-device stop condition, token for token
+                    if tok == st.req.eos_id or len(st.out) >= st.req.max_new \
+                            or st.pos >= self.max_len - 1:
+                        if slot in self._streams:
+                            # mid-stream hypothesis complete: pause the lane
+                            # (keep the slot and its growing encoder cache);
+                            # stream_finalize re-anchors and decodes the
+                            # final transcript.
+                            self.active.pop(slot)
+                        else:
+                            st.done = True
+                            self.active.pop(slot)
+                            self._free_slot(slot)
+                            finished.append(st)
+                        break
+                if self.paged:
+                    # advance the lane's valid-token extent (fragmentation
+                    # accounting only; allocation already covered max_new;
+                    # no-op for lanes freed above)
+                    self.pages.note_len(slot, st.pos)
+            return finished
 
     def step_end(self, pending: Optional[PendingTick]
                  ) -> list[RequestState]:
