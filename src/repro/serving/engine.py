@@ -484,6 +484,9 @@ class ServeEngine:
         self._lane_eos = jnp.zeros((n_slots,), jnp.int32)
         self._lane_max = jnp.zeros((n_slots,), jnp.int32)
         self._lane_out = jnp.zeros((n_slots,), jnp.int32)
+        # _set_lane's program: the seven vectors above, donated; one
+        # compile per engine (slot and values are traced)
+        self._write_lane = jax.jit(_write_lane, donate_argnums=(0,))
         self._decode_fns: dict[int, Any] = {}   # block size -> fused jit
         self._prefill_fns: dict[tuple, Any] = {}
         # streaming audio: open streams by slot + jitted encoder helpers
@@ -792,15 +795,23 @@ class ServeEngine:
     def _set_lane(self, slot: int, *, token: int, pos: int, enc_len: int,
                   eos: int, max_new: int, n_out: int,
                   active: bool) -> None:
-        """Write one lane's device-resident decode state (admission /
-        anchor / free — never the per-tick hot path)."""
-        self._tokens = self._tokens.at[slot, 0].set(token)
-        self._pos = self._pos.at[slot].set(pos)
-        self._enc_lens = self._enc_lens.at[slot].set(enc_len)
-        self._lane_eos = self._lane_eos.at[slot].set(eos)
-        self._lane_max = self._lane_max.at[slot].set(max_new)
-        self._lane_out = self._lane_out.at[slot].set(n_out)
-        self._lane_active = self._lane_active.at[slot].set(active)
+        """Write one lane's device-resident decode state: at admission,
+        at a stream's anchor, and when a lane is freed (``_free_slot``,
+        which ``step_replay`` runs on the tick path for every finished
+        lane). One launch of the donated ``_write_lane`` program with
+        the values in one small host array."""
+        with tracing.span("engine.set_lane") as sp:
+            if sp is not None:
+                sp.attrs["slot"] = slot
+            row = np.array([slot, token, pos, enc_len, eos, max_new, n_out,
+                            active], np.int32)
+            with _quiet_donation():
+                (self._tokens, self._pos, self._enc_lens, self._lane_eos,
+                 self._lane_max, self._lane_out, self._lane_active) = \
+                    self._write_lane(
+                        (self._tokens, self._pos, self._enc_lens,
+                         self._lane_eos, self._lane_max, self._lane_out,
+                         self._lane_active), row)
 
     # ------------------------------------------------------------------
     def validate(self, req: Request) -> Optional[Rejection]:
@@ -1632,6 +1643,18 @@ def _cache_bytes(tree) -> tuple[int, int]:
             st += b
         return kv, st
     return 0, sum(int(l.nbytes) for l in jax.tree.leaves(tree))
+
+
+def _write_lane(lanes: tuple, row) -> tuple:
+    """One lane of the decode state written: ``lanes`` is (tokens, pos,
+    enc_lens, eos, max_new, n_out, active), ``row`` the int32 vector
+    [slot, token, pos, enc_len, eos, max_new, n_out, active]."""
+    tokens, pos, enc_lens, eos, max_new, n_out, active = lanes
+    slot = row[0]
+    return (tokens.at[slot, 0].set(row[1]), pos.at[slot].set(row[2]),
+            enc_lens.at[slot].set(row[3]), eos.at[slot].set(row[4]),
+            max_new.at[slot].set(row[5]), n_out.at[slot].set(row[6]),
+            active.at[slot].set(row[7] != 0))
 
 
 def _scatter_slot(pool: Any, one: Any, slot) -> Any:

@@ -246,6 +246,116 @@ def test_one_host_sync_per_tick():
     assert eng._host_syncs - syncs0 == n == eng._ticks
 
 
+# ------------------------------------------------- lane-state writes
+
+LANES = ("_tokens", "_pos", "_enc_lens", "_lane_eos", "_lane_max",
+         "_lane_out", "_lane_active")
+
+
+def _lanes(eng):
+    return {name: np.asarray(getattr(eng, name)) for name in LANES}
+
+
+def _np_write(lanes, slot, *, token, pos, enc_len, eos, max_new, n_out,
+              active):
+    """The seven per-lane writes ``_set_lane`` stands for, in NumPy."""
+    out = {name: a.copy() for name, a in lanes.items()}
+    out["_tokens"][slot, 0] = token
+    for name, v in (("_pos", pos), ("_enc_lens", enc_len),
+                    ("_lane_eos", eos), ("_lane_max", max_new),
+                    ("_lane_out", n_out), ("_lane_active", active)):
+        out[name][slot] = v
+    return out
+
+
+def _assert_lanes(eng, want):
+    got = _lanes(eng)
+    for name in LANES:
+        assert got[name].dtype == want[name].dtype, name
+        assert got[name].shape == want[name].shape, name
+        np.testing.assert_array_equal(got[name], want[name], err_msg=name)
+
+
+def _free(eng, lanes, slot):
+    return _np_write(lanes, slot, token=0, pos=0, enc_len=0, eos=0,
+                     max_new=0, n_out=0, active=False)
+
+
+@pytest.mark.parametrize("path", ["admit", "anchor", "free"])
+def test_set_lane_writes_what_the_eager_updates_wrote(path):
+    """Admission, a stream's anchor and a freed lane leave all seven
+    lane vectors, dtypes and shapes as the seven ``.at[slot].set``
+    updates would: element for element against a NumPy model, the
+    other lanes untouched."""
+    cfg, model, params = _setup()
+    rng = np.random.default_rng(3)
+    eng = _engine(model, params, decode_block=2)
+    want = _lanes(eng)
+    assert want["_tokens"].shape == (4, 1)
+    assert want["_lane_active"].dtype == np.bool_
+    frames = _frames(cfg, rng, lens=(8, 12))
+    for uid, (f, max_new) in enumerate(zip(frames, (3, 9))):
+        st = eng.admit(AudioRequest(uid=uid, tokens=[5, 6, 7],
+                                    max_new=max_new, eos_id=-2,
+                                    enc_frames=f))
+        want = _np_write(want, st.slot, token=st.out[0], pos=3,
+                         enc_len=f.shape[0], eos=-2, max_new=max_new,
+                         n_out=1, active=True)
+        if path == "admit":
+            _assert_lanes(eng, want)
+    if path == "anchor":
+        chunks = [rng.standard_normal((4, cfg.d_model)).astype(np.float32)
+                  * 0.5 for _ in range(2)]
+        st = eng.open_stream(StreamingAudioRequest(
+            uid=7, tokens=[5, 6], max_new=4, eos_id=-2, chunks=chunks))
+        _assert_lanes(eng, want)        # opening writes no lane state
+        eng.stream_feed(st, chunks[0])
+        want = _np_write(want, st.slot, token=st.out[0], pos=2, enc_len=4,
+                         eos=-2, max_new=4, n_out=1, active=True)
+        _assert_lanes(eng, want)
+    if path == "free":
+        # the first lane (max_new 3) finishes in the first tick of two
+        # steps; replay frees it
+        pending = eng.step_begin()
+        tok_blk, emit_blk = eng.step_fetch(pending)
+        want = _lanes(eng)               # as the decode program left it
+        freed = eng.step_replay(pending, tok_blk, emit_blk)
+        assert [st.req.uid for st in freed] == [0]
+        want = _free(eng, want, freed[0].slot)
+        _assert_lanes(eng, want)
+        st = eng.active[next(iter(eng.active))]
+        eng.abort(st)
+        _assert_lanes(eng, _free(eng, want, st.slot))
+
+
+def test_lane_write_donates_the_lane_vectors():
+    """The lane-update program aliases each of the seven vectors to its
+    output, so a donation-capable backend writes one lane in place."""
+    cfg, model, params = _setup()
+    eng = _engine(model, params)
+    lanes = tuple(getattr(eng, name) for name in LANES)
+    row = np.zeros(8, np.int32)
+    txt = eng._write_lane.lower(lanes, row).as_text()
+    assert txt.count("tf.aliasing_output") == len(LANES), \
+        txt.count("tf.aliasing_output")
+
+
+def test_lane_write_compiles_once_for_every_slot():
+    """Slot and values are traced: writes at every slot, with admission
+    and freeing values, leave one entry in the program's jit cache."""
+    cfg, model, params = _setup()
+    rng = np.random.default_rng(4)
+    eng = _engine(model, params)
+    for uid, f in enumerate(_frames(cfg, rng, lens=(8, 12, 8, 10))):
+        eng.admit(AudioRequest(uid=uid, tokens=[5, 6, 7], max_new=4,
+                               eos_id=-2, enc_frames=f))
+    assert sorted(eng.active) == [0, 1, 2, 3]
+    for _ in range(3):                  # max_new 4: three decode steps
+        eng.step()
+    assert sorted(eng.free) == [0, 1, 2, 3]
+    assert eng._write_lane._cache_size() == 1
+
+
 # -------------------------------------------------- energy accounting
 
 

@@ -148,10 +148,33 @@ def test_one_admission_span_per_request(traced):
         assert by_id[sp.parent].name == "gateway.admit"
         assert by_id[by_id[sp.parent].parent].name == "gateway.tick"
         assert [c.name for c in _children(sp, spans)] == [
-            "engine.admit.inputs", "engine.prefill", "engine.first_token"]
+            "engine.admit.inputs", "engine.prefill", "engine.first_token",
+            "engine.set_lane"]
         assert sp.attrs["enc_s"] > 0 and sp.attrs["bucket"] >= 2
     names = collections.Counter(s.name for s in spans)
     assert names["frontend.frames"] == N_REQUESTS
+
+
+def test_lane_writes_nest_under_admission_and_replay(traced):
+    """``engine.set_lane`` runs once in each admission, after its first
+    token, and once per lane freed in a replay, with the lane's slot;
+    every lane an admission wrote is freed under a replay."""
+    spans, results, _ = traced
+    by_id = {s.id: s for s in spans}
+    writes = [s for s in spans if s.name == "engine.set_lane"]
+    under = collections.defaultdict(list)
+    for w in writes:
+        up = by_id[w.parent]
+        assert up.start <= w.start <= w.end <= up.end
+        assert 0 <= w.attrs["slot"] < N_SLOTS
+        under[up.name].append(w.attrs["slot"])
+    assert set(under) == {"engine.admit", "engine.replay"}
+    assert len(under["engine.admit"]) == N_REQUESTS
+    assert sorted(under["engine.replay"]) == sorted(under["engine.admit"])
+    for w in writes:
+        if by_id[w.parent].name == "engine.admit":
+            ft = _children(by_id[w.parent], spans)[2]
+            assert ft.name == "engine.first_token" and ft.end <= w.start
 
 
 def test_fetch_runs_on_the_executor_under_its_tick(traced):
