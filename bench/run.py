@@ -5,8 +5,9 @@
 
 from the root of a checkout. The cell (``BENCHMARK.json``'s
 ``workloads``) names a configuration and a traffic mix; their files, the
-correctness limits, the per-layer metric readers, the kernel models and
-the peak table are found by name under ``bench/`` (``spec.py``).
+configuration's family module, the correctness limits, the per-layer
+metric readers, the kernel models and the peak table are found by name
+under ``bench/`` (``spec.py``).
 
 A run builds the deployment the configuration states, makes the weights
 from the seed on the device, warms up every shape the cell's traffic
@@ -16,7 +17,7 @@ metrics; with ``--trace 1`` a profiler trace of the window gives its
 per-layer metrics and the ``breakdown``. At the window's close a
 sample of the lanes in flight is read back from the engine's KV pool;
 then the engine is freed, and a sample of the served requests and those
-lanes are checked against the plain reference (``reference.py``):
+lanes are checked against the family's plain reference:
 ``correct`` is whether every number compared stays within its limit in
 ``limits/<cell>.json``.
 
@@ -43,6 +44,7 @@ import time
 
 T_START = time.monotonic()
 TRACE_S = 5.0      # a traced run traces this much of its window
+KV_OFF = "_kv_off"  # suffix of the limit on one kind of lane planes
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
@@ -55,6 +57,7 @@ class RunData:
 
     def __init__(self, *, cell, cfg, mix, peaks, runner, trace, t1):
         self.cell, self.cfg, self.mix, self.peaks = cell, cfg, mix, peaks
+        self.family = runner.family
         self.t0, self.t1 = runner.t0, t1
         self.done = runner.done
         self.spans = runner.spans.done
@@ -132,20 +135,19 @@ def _unfed(d, mix, runner):
 
 
 # ---------------------------------------------------------- correctness
-def check(runner, cfg: dict, mix: dict, limits: dict, params, seed: int,
-          log) -> tuple:
-    """Judge what the timed path produced by the plain reference.
+def check(runner, cfg: dict, limits: dict, params, seed: int, log) -> tuple:
+    """Judge what the timed path produced by the family's plain reference.
 
     Two samples, both drawn from the seed: completed requests, with the
     longest (prompt plus output) always in it, whose served tokens are
     judged; and the lanes in flight at the window's close
     (``Runner.take_lanes``), whose served tokens are judged and whose
-    cached K/V is compared with the reference's (the share of elements
-    off it, ``reference.judge``). Returns (correct, compared) where
+    planes are compared with the reference's: for each kind ``<kind>``
+    that the limits name as ``<kind>_kv_off``, the share of elements off
+    it in the worst plane (``judge``). Returns (correct, compared) where
     compared maps each number to its value and limit; a number that
-    could not be read (no lane in flight) is None and fails."""
-    import numpy as np
-    import reference
+    could not be read (no lane in flight, a kind the family did not
+    read) is None and fails."""
     ok = [d for d in runner.done if d.ok and d.result.tokens]
     if not ok:
         return False, {"checked_requests": {"value": 0,
@@ -155,33 +157,23 @@ def check(runner, cfg: dict, mix: dict, limits: dict, params, seed: int,
     rng = random.Random(seed)
     sample = [longest] + rng.sample(rest, min(len(rest),
                                               limits["requests"] - 1))
-    ref = reference.Reference(cfg, params)
-    d_model = cfg["config"]["d_model"]
+    ref = runner.family.Reference(cfg, params)
     short, gaps = 0, []
-    kv = {"self": 0.0, "cross": 0.0}
+    kinds = [k[:-len(KV_OFF)] for k in limits if k.endswith(KV_OFF)]
+    kv = dict.fromkeys(kinds, 0.0) if runner.lanes else \
+        dict.fromkeys(kinds)
     for d in sample:
         toks = list(d.result.tokens)
         short += len(toks) != d.req.max_new
-        if mix["kind"] == "stream":
-            pieces = traffic_chunks(d.req, mix)
-            fr = np.concatenate([reference.frames(runner.bank.audio(d.req,
-                                                                   a, b),
-                                                  d_model)
-                                 for a, b in pieces])
-            chunk = fr.shape[0] // len(pieces)
-        else:
-            fr = reference.frames(runner.bank.audio(d.req), d_model)
-            chunk = None
-        g, _ = ref.judge(fr, chunk, d.req.prompt, toks)
+        g, _ = ref.judge(runner, d.req, toks)
         gaps.extend(float(x) for x in g)
     for lane in runner.lanes:
-        fr = reference.frames(runner.bank.audio(lane.req), d_model)
-        g, kvg = ref.judge(fr, None, lane.req.prompt, lane.out,
-                           lane.planes)
+        g, off = ref.judge(runner, lane.req, lane.out, lane.planes)
         gaps.extend(float(x) for x in g)
         for kind in kv:
-            kv[kind] = None if kvg is None or kv[kind] is None \
-                else max(kv[kind], kvg[kind])
+            got = None if off is None else off.get(kind)
+            kv[kind] = None if got is None or kv[kind] is None \
+                else max(kv[kind], got)
     off = sorted(g for g in gaps if g > 0)
     log(f"checked {len(sample)} requests and {len(runner.lanes)} lanes in "
         f"flight, {len(gaps)} served tokens, {len(off)} off the "
@@ -192,27 +184,22 @@ def check(runner, cfg: dict, mix: dict, limits: dict, params, seed: int,
         "short_outputs": {"value": short, "limit": 0},
     }
     if limits.get("lanes"):
-        if not runner.lanes:
-            kv = {kind: None for kind in kv}
         for kind, v in kv.items():
-            compared[f"{kind}_kv_off"] = {"value": v,
-                                          "limit": limits[f"{kind}_kv_off"]}
+            compared[f"{kind}{KV_OFF}"] = {
+                "value": v, "limit": limits[f"{kind}{KV_OFF}"]}
     correct = all(c["value"] is not None and c["value"] <= c["limit"]
                   for c in compared.values())
     return correct, compared
 
 
-def traffic_chunks(req, mix) -> list:
-    import traffic
-    return [(a, b) for _, a, b in traffic.chunks_of(req, mix["chunk_s"])]
-
-
 # ------------------------------------------------------------------- run
 def run_cell(bench: dict, cell: dict, cfg: dict, mix: dict, limits: dict,
              *, seed: int, seconds: float, trace: bool, peaks: dict,
-             log=print) -> dict:
+             family=None, log=print) -> dict:
     """One run of ``cell``; returns the result object (without the
-    device block's platform fields, which the caller adds)."""
+    device block's platform fields, which the caller adds). ``family``
+    is the configuration's family module, by default the one its
+    ``family`` names under ``bench/families/``."""
     import jax
     import jax.monitoring
     import model as bench_model
@@ -220,18 +207,20 @@ def run_cell(bench: dict, cell: dict, cfg: dict, mix: dict, limits: dict,
     import spec
     import trace_reduce
 
+    family = family or spec.family_module(cfg["family"])
     loads = []
     jax.monitoring.register_event_listener(
         lambda name, **kw: loads.append(time.monotonic())
         if name == "/jax/compilation_cache/compile_requests_use_cache"
         else None)
     dep = cfg["deployment"]
-    arch = bench_model.arch_config(cfg)
+    arch = family.arch_config(cfg)
     log(f"set-up: {time.monotonic() - T_START:.3f} s to JAX on "
         f"{jax.devices()[0].device_kind}")
-    params = bench_model.make_weights(serve._build(arch), seed)
+    params = bench_model.make_weights(serve._build(arch), seed,
+                                      family.init_leaf)
     runner = serve.Runner(cfg, mix, bench_model.served(params, dep),
-                          seed=seed, seconds=seconds,
+                          family=family, seed=seed, seconds=seconds,
                           lanes=limits.get("lanes", 0), log=log)
     log(f"set-up: {time.monotonic() - T_START:.3f} s to weights, engine "
         f"and {len(runner.reqs)} requests")
@@ -293,7 +282,7 @@ def run_cell(bench: dict, cell: dict, cfg: dict, mix: dict, limits: dict,
     # free the program's state before the reference runs
     runner.engine = runner.gw = None
     gc.collect()
-    correct, compared = check(runner, cfg, mix, limits, params, seed, log)
+    correct, compared = check(runner, cfg, limits, params, seed, log)
     out = {"correct": bool(correct and attempted > 0),
            "attempted": attempted, "failed": failed, "metrics": metrics,
            "device": {"memory_peak_bytes": int(mem), **device_extra}}

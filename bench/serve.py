@@ -1,14 +1,15 @@
 """Drive one cell's traffic through the served path and time it.
 
 The system under test is a ``repro.gateway.Gateway`` over a
-``ServeEngine``, built as the configuration's ``deployment`` says. Every
-request's audio passes through the frontend (``audio_frames``) once it
-is due, inside the client coroutine, so the frontend is on the timed
-path. Spans are taken from this file by wrapping calls into each layer:
-the frontend call, the engine instance's ``admit`` and ``stream_feed``,
-and each tick from ``step_begin`` to the end of ``step_replay``. With
-tracing on they also go into the profiler's trace as
-``TraceAnnotation``s, on the device trace's clock.
+``ServeEngine``, built as the configuration's ``deployment`` says
+through its family module (``families/<family>.py``), which also says
+how a request reaches the gateway (for Whisper, through the frontend,
+inside the client coroutine, so that it is on the timed path). Spans
+are taken from this file by wrapping calls into each layer: the engine
+instance's ``admit`` and ``stream_feed``, and each tick from
+``step_begin`` to the end of ``step_replay`` (a family adds its own,
+such as Whisper's ``frontend``). With tracing on they also go into the
+profiler's trace as ``TraceAnnotation``s, on the device trace's clock.
 """
 
 from __future__ import annotations
@@ -19,14 +20,11 @@ import contextlib
 import dataclasses
 import random
 import time
-from typing import Optional
 
 import numpy as np
 
 import traffic
 
-BANK = 8             # distinct base waveforms per run
-BANK_S = 32.0        # seconds of each
 DRAIN_S = 60.0       # how long past the window an open-loop request may take
 STREAM_GRACE_S = 2.0
 LANES_WAIT_S = 10.0  # how long past the window a lane snapshot may wait
@@ -104,26 +102,6 @@ def instrument(engine, spans: Spans, after_replay=None) -> None:
     engine.step_begin, engine.step_replay = begin_, replay_
 
 
-class Bank:
-    """The run's waveforms: ``BANK`` base signals from the seed; a
-    request reads a slice chosen by its ``wave_seed``."""
-
-    def __init__(self, seed: int):
-        self.waves = [traffic.waveform(BANK_S, seed * BANK + i)
-                      for i in range(BANK)]
-
-    def audio(self, req, t0_s: float = 0.0,
-              t1_s: Optional[float] = None) -> np.ndarray:
-        w = self.waves[req.wave_seed % BANK]
-        n = int(round(req.audio_s * traffic.SAMPLE_RATE))
-        room = len(w) - n
-        off = (req.wave_seed // BANK) % (room + 1)
-        a = off + int(round(t0_s * traffic.SAMPLE_RATE))
-        b = off + (n if t1_s is None
-                   else int(round(t1_s * traffic.SAMPLE_RATE)))
-        return w[a:b]
-
-
 @dataclasses.dataclass
 class Done:
     """What one request (or streaming session) did, on the monotonic
@@ -147,26 +125,29 @@ class Done:
 class Lane:
     """A one-shot request in flight at the window's close, as the engine
     held it after its last replayed tick: the tokens it had emitted and
-    the K/V its lane had cached, ``{"self"|"cross": {"k"|"v": float32
-    (layer, position, head, dim)}}`` over the positions written (None
-    where the lane's positions did not match its tokens)."""
+    the planes its lane held, as the family's ``read_lane`` reads them,
+    ``{kind: {name: float32 array}}`` (None where the lane's positions
+    did not match its tokens)."""
 
     req: traffic.Req
     out: list
     planes: dict
 
 
-def lane_planes(cache, slot: int, n_self: int, n_cross: int) -> dict:
-    """One lane's cached K/V, read back from the engine's pool as
-    float32: bf16 planes as they are, q8_0 planes (int8 codes, one f16
-    scale per block along the head dim) decoded."""
+def kv_planes(trees: dict, slot: int) -> dict:
+    """One lane's K/V, read back from the engine's pool as float32 in one
+    transfer: ``trees`` maps a kind to ``(planes, n)``, planes stacked
+    ``(layer, lane, position, head, dim)``, of which the first ``n``
+    positions are read; bf16 planes as they are, q8_0 planes (int8
+    codes, one f16 scale per block along the head dim) decoded. Returns
+    ``{kind: {"k"|"v": (layer, position, head, dim)}}``."""
     import jax
-    layers = jax.device_get({kind: {key: a[:, slot] for key, a in
-                                    cache["layers"][kind].items()}
-                             for kind in ("self", "cross")})
+    got = jax.device_get({kind: {key: a[:, slot] for key, a in
+                                 planes.items()}
+                          for kind, (planes, _) in trees.items()})
     out = {}
-    for kind, n in (("self", n_self), ("cross", n_cross)):
-        p = layers[kind]
+    for kind, (_, n) in trees.items():
+        p = got[kind]
         if set(p) == {"k", "v"}:
             out[kind] = {key: np.asarray(p[key][:, :n], np.float32)
                          for key in ("k", "v")}
@@ -183,31 +164,26 @@ def lane_planes(cache, slot: int, n_self: int, n_cross: int) -> dict:
 
 
 class Runner:
-    """One cell's run: build, warm up, lead in, measure, drain."""
+    """One cell's run: build, warm up, lead in, measure, drain.
+    ``family`` is the configuration's family module."""
 
-    def __init__(self, cfg: dict, mix: dict, params, *, seed: int,
+    def __init__(self, cfg: dict, mix: dict, params, *, family, seed: int,
                  seconds: float, lanes: int = 0, log=print):
-        from repro.audio.features import audio_frames
         from repro.gateway import Gateway
         from repro.gateway.slo import DEFAULT_CLASSES
         from repro.serving.engine import ServeEngine
-        import model as bench_model
         self.cfg, self.mix, self.seconds = cfg, mix, seconds
+        self.family = family
         self.log = log
-        dep = cfg["deployment"]
         self.engine = ServeEngine(
-            _build(bench_model.arch_config(cfg)), params,
-            n_slots=dep["n_slots"],
-            max_len=dep["max_len"], enc_len=dep["enc_len"],
-            cache_dtype=dep["cache_dtype"])
+            _build(family.arch_config(cfg)), params,
+            **family.engine_options(cfg["deployment"]))
         self.spans = Spans()
         instrument(self.engine, self.spans, self._after_replay)
         self.gw = Gateway(self.engine)
         self.slo = {c.name: c for c in DEFAULT_CLASSES}[mix["slo"]]
-        self.d_model = cfg["config"]["d_model"]
-        self._frames = audio_frames
-        self.bank = Bank(seed)
-        self.reqs = traffic.generate(mix, cfg, seed, seconds)
+        self.inputs = family.inputs(seed)
+        self.reqs = traffic.generate(mix, cfg, family.prompt, seed, seconds)
         self.done: list[Done] = []
         self.lateness: list[float] = []
         self.t0 = self.t1 = None
@@ -215,25 +191,24 @@ class Runner:
         self.lanes_wanted, self.lanes = lanes, []
         self._lane_rng = random.Random(seed)
         self._want_lanes = None
-        self._inflight = {}       # id(frames) -> (request, frames)
+        self._inflight = {}       # family.lane_key -> request
 
     # ------------------------------------------------------------ pieces
-    def frontend(self, wave):
-        with self.spans.span("frontend"):
-            fr = self._frames(wave, self.d_model)
-            fr.block_until_ready()
-        return fr
+    @contextlib.contextmanager
+    def in_flight(self, key, req):
+        """Register ``req`` under ``key`` while the family's ``submit``
+        awaits it: the lanes taken at the window's close are found by
+        ``family.lane_key`` of the engine's request."""
+        self._inflight[key] = req
+        try:
+            yield
+        finally:
+            self._inflight.pop(key, None)
 
     async def _oneshot(self, req, due: float, max_new=None) -> Done:
         d = Done(req, due)
-        fr = self.frontend(self.bank.audio(req))
-        self._inflight[id(fr)] = (req, fr)
-        try:
-            d.result = await self.gw.submit_audio(
-                fr, tokens=list(req.prompt), eos_id=-1, slo=self.slo,
-                max_new=max_new or req.max_new, audio_s=req.audio_s)
-        finally:
-            self._inflight.pop(id(fr), None)
+        d.result = await self.family.submit(self, req,
+                                            max_new or req.max_new)
         return d
 
     # ------------------------------------------------- lanes at the close
@@ -255,10 +230,9 @@ class Runner:
         eng = self.engine
         live = []
         for slot, st in sorted(eng.active.items()):
-            fr = getattr(st.req, "enc_frames", None)
-            got = self._inflight.get(id(fr))
-            if got is not None and got[1] is fr:
-                live.append((slot, st, got[0]))
+            req = self._inflight.get(self.family.lane_key(st.req))
+            if req is not None:
+                live.append((slot, st, req))
         if not live:
             return
         for slot, st, req in self._lane_rng.sample(
@@ -268,12 +242,11 @@ class Runner:
             # holds anything else is kept with no planes, and fails
             held = list(st.req.tokens) == list(req.prompt) and \
                 st.pos == len(req.prompt) + len(st.out) - 1
-            self.lanes.append(Lane(req, list(st.out), lane_planes(
-                eng.cache, slot, st.pos, st.req.enc_frames.shape[0])
-                if held else None))
+            self.lanes.append(Lane(req, list(st.out), self.family.read_lane(
+                eng.cache, slot, st) if held else None))
         self._want_lanes.set()
 
-    async def _sleep_until(self, t: float) -> None:
+    async def sleep_until(self, t: float) -> None:
         dt = t - time.monotonic()
         if dt > 0:
             await asyncio.sleep(dt)
@@ -301,8 +274,9 @@ class Runner:
             # gateway's tick / admit estimators
             for _ in range(3):
                 await asyncio.gather(*[
-                    self._session(warm[0], None, paced=False, max_new=2,
-                                  keep=False) for _ in range(2)])
+                    self.family.session(self, warm[0], None, paced=False,
+                                        max_new=2, keep=False)
+                    for _ in range(2)])
             return
         # one at a time first, each until it is served: while programs
         # compile, a queued request can pass its deadline and be shed,
@@ -312,8 +286,9 @@ class Runner:
                 if (await self._oneshot(r, time.monotonic(), 2)).ok:
                     break
             else:
-                raise RuntimeError(f"warm-up request of {r.audio_s} s "
-                                   f"was never served")
+                raise RuntimeError(f"warm-up request of {len(r.prompt)} "
+                                   f"prompt tokens and {r.audio_s} s of "
+                                   f"audio was never served")
         for n in (len(warm), 4, 4, 4):
             await asyncio.gather(*[self._oneshot(r, time.monotonic(), 2)
                                    for r in (warm * 4)[:n]])
@@ -346,11 +321,11 @@ class Runner:
                 await asyncio.sleep(0.002)
             tasks.append(asyncio.create_task(client()))
             await asyncio.sleep(0)
-        await self._sleep_until(t_start + lead)
+        await self.sleep_until(t_start + lead)
         self.done.clear()
         self.t0 = time.monotonic()
         self.on_window_start()
-        await self._sleep_until(self.t0 + self.seconds)
+        await self.sleep_until(self.t0 + self.seconds)
         self.t1 = time.monotonic()
         self.on_window_end()
         await self.take_lanes()
@@ -364,16 +339,16 @@ class Runner:
 
         async def one(req):
             due = t_base + req.due
-            await self._sleep_until(due)
+            await self.sleep_until(due)
             self.lateness.append(time.monotonic() - due)
             d = await self._oneshot(req, due)
             self.done.append(d)
 
         tasks = [asyncio.create_task(one(r)) for r in self.reqs]
-        await self._sleep_until(t_base)
+        await self.sleep_until(t_base)
         self.t0 = time.monotonic()
         self.on_window_start()
-        await self._sleep_until(t_base + self.seconds)
+        await self.sleep_until(t_base + self.seconds)
         self.t1 = time.monotonic()
         self.on_window_end()
         await self.take_lanes()
@@ -382,39 +357,6 @@ class Runner:
             t.cancel()
         await asyncio.gather(*tasks, return_exceptions=True)
 
-    async def _session(self, req, t_base: Optional[float], *,
-                       paced: bool = True, max_new=None,
-                       keep: bool = True) -> Done:
-        """One streaming session: open, feed each chunk once it is due
-        (all at once when not ``paced``), finalize. A session cancelled
-        at the end of the run keeps the record of what it was fed."""
-        d = Done(req, 0.0 if t_base is None else t_base + req.due)
-        if keep:
-            self.done.append(d)
-        sess = None
-        try:
-            sess = await self.gw.open_session(
-                tokens=list(req.prompt), max_new=max_new or req.max_new,
-                eos_id=-1, slo=self.slo, audio_s=req.audio_s)
-            for due, a, b in traffic.chunks_of(req, self.mix["chunk_s"]):
-                if paced:
-                    due_abs = t_base + due
-                    await self._sleep_until(due_abs)
-                    if self.t0 is not None:
-                        self.lateness.append(time.monotonic() - due_abs)
-                else:
-                    due_abs = time.monotonic()
-                fr = self.frontend(self.bank.audio(req, a, b))
-                fed = time.monotonic()
-                await sess.feed(fr)
-                d.feeds.append((due_abs, fed))
-            d.result = await sess.finalize()
-        except asyncio.CancelledError:
-            if sess is not None:
-                d.result = await sess.cancel()
-            raise
-        return d
-
     async def _stream(self) -> None:
         lead = float(self.mix["lead_s"])
         t_base = time.monotonic() + lead
@@ -422,15 +364,15 @@ class Runner:
         async def start(req):
             # a session that began before the lead-in has its past audio
             # fed at once: the window opens on sessions at every stage
-            await self._sleep_until(t_base + req.due)
-            await self._session(req, t_base)
+            await self.sleep_until(t_base + req.due)
+            await self.family.session(self, req, t_base)
 
         live = [r for r in self.reqs if r.due + r.audio_s > -lead]
         tasks = [asyncio.create_task(start(r)) for r in live]
-        await self._sleep_until(t_base)
+        await self.sleep_until(t_base)
         self.t0 = time.monotonic()
         self.on_window_start()
-        await self._sleep_until(t_base + self.seconds)
+        await self.sleep_until(t_base + self.seconds)
         self.t1 = time.monotonic()
         self.on_window_end()
         await asyncio.sleep(STREAM_GRACE_S)

@@ -7,7 +7,11 @@ file, so a new cell, mix, metric or kernel needs new files and entries
 only:
 
 * ``configs/<config>.json``  — a model configuration (the path is the
-  ``file`` of its ``configs`` entry);
+  ``file`` of its ``configs`` entry), which names its ``family``;
+* ``families/<family>.py``   — everything that depends on the model
+  family: the program's ``ArchConfig``, the weights, the engine's
+  options, the prompts, how a request reaches the gateway, the read-back
+  of a lane, the plain reference and the model FLOPs (``family_module``);
 * ``mixes/<traffic>.json``   — the parameters of a traffic mix;
 * ``limits/<cell>.json``     — the correctness limits of one cell;
 * ``metrics/<metric>.py``    — a per-layer metric's reader, ``read(run)``;
@@ -78,6 +82,31 @@ def kernel_model(name: str, base: Path = BENCH):
     """The module of kernel ``name``: ``TRACE_NAMES`` and
     ``cost(shapes) -> (flops, bytes)``."""
     return _module(Path(base) / "kernels" / f"{name}.py", "kernel")
+
+
+def family_module(name: str, base: Path = BENCH):
+    """The module of model family ``name``, which gives:
+
+    * ``arch_config(cfg)`` — the program's ``ArchConfig``;
+    * ``init_leaf(path, shape, key)`` — one float32 weight leaf, drawn
+      from ``key``, by its path in the parameter tree;
+    * ``engine_options(deployment)`` — the ``ServeEngine`` keywords;
+    * ``prompt(cfg, n, rng)`` — the prompt token ids of a request;
+    * ``inputs(seed)`` — what else a run draws its requests' content
+      from (None where the prompt is all);
+    * ``submit(runner, req, max_new)`` — the coroutine by which a request
+      reaches the gateway, inside ``runner.in_flight(lane_key, req)``;
+    * ``session(runner, req, t_base, ...)`` — one streaming session
+      (families whose mixes stream only);
+    * ``lane_key(request)`` — the key ``submit`` registered, from the
+      engine's request;
+    * ``read_lane(cache, slot, state)`` — one lane's planes, float32,
+      ``{kind: {name: array}}``;
+    * ``Reference(cfg, params)`` with ``judge(runner, req, tokens,
+      planes=None)`` -> (token gaps, ``{kind: share off}`` or None);
+    * ``request_flops(cfg, req, n_out)`` — the model FLOPs of one
+      completed request."""
+    return _module(Path(base) / "families" / f"{name}.py", "family")
 
 
 def load_peaks(device_kind: str, base: Path = BENCH) -> dict:

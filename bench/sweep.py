@@ -65,13 +65,14 @@ def main(argv=None) -> int:
     if jax.devices()[0].platform != "tpu":
         print("sweep: needs the chip", file=sys.stderr)
         return 2
+    family = spec.family_module(cfg["family"])
     params = bench_model.make_weights(
-        serve._build(bench_model.arch_config(cfg)), args.seed)
+        serve._build(family.arch_config(cfg)), args.seed, family.init_leaf)
     for rate in (float(r) for r in args.rates.split(",")):
         mix = copy.deepcopy(spec.load_mix(cell["traffic"]))
         mix["rate_per_s"] = rate
-        runner = serve.Runner(cfg, mix, params, seed=args.seed,
-                              seconds=args.seconds,
+        runner = serve.Runner(cfg, mix, params, family=family,
+                              seed=args.seed, seconds=args.seconds,
                               log=lambda m: print(m, file=sys.stderr))
         runner.run(lambda: None, lambda: None)
         e2e, attempted, failed = run.end_to_end(runner, mix)

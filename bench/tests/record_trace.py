@@ -33,17 +33,17 @@ def main() -> int:
     import serve
     import spec
     import trace_reduce
-    import traffic
     from repro.audio.features import audio_frames
     from repro.serving.engine import AudioRequest, ServeEngine
     cfg = spec.load_config(spec.load_benchmark(), "whisper-tiny.en")
-    model = serve._build(bench_model.arch_config(cfg))
-    params = bench_model.make_weights(model, 1)
+    family = spec.family_module(cfg["family"])
+    model = serve._build(family.arch_config(cfg))
+    params = bench_model.make_weights(model, 1, family.init_leaf)
     engine = ServeEngine(model, params, n_slots=4, max_len=64,
                          enc_len=1500)
     spans = serve.Spans()
     serve.instrument(engine, spans)
-    wave = traffic.waveform(2.0, 1)
+    wave = family.waveform(2.0, 1)
     prompt = list(cfg["prompt"]["sot_sequence"])
 
     def once():

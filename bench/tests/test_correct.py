@@ -2,9 +2,10 @@
 run whose timed path is broken underneath fails.
 
 The harness runs as ``bench/run.py`` runs it, minus the look for a chip:
-the tiny configuration (``tiny.py``) through the gateway and engine on
-the CPU, checked against the plain reference with the cell's own
-limits."""
+the tiny configurations (``tiny.py``) through the gateway and engine on
+the CPU, checked against their family's plain reference with the cell's
+own limits: Whisper on the benchmark's mixes, and the test-only dense
+family (``families/dense.py``) on a bursty text mix, ``chat``."""
 
 import copy
 
@@ -15,8 +16,9 @@ import run
 import spec
 import tiny
 
+# the stream's and the chat's cells are not in BENCHMARK.json
 CELLS = {"backlog": "tiny_en.backlog", "poisson": "tiny_en.poisson",
-         "stream": "tiny_en.stream"}   # the stream's is not in BENCHMARK.json
+         "stream": "tiny_en.stream", "chat": "dense.chat"}
 
 
 def _limits(mixname):
@@ -32,11 +34,18 @@ def _limits(mixname):
 
 def _run(mixname, cfg=None, seconds=2.0, seed=2**32 + 3):
     bench = spec.load_benchmark()
-    cell = {"name": CELLS[mixname], "config": "whisper-test",
+    if mixname == "chat":
+        cfg, mix, limits = cfg or tiny.DENSE, tiny.CHAT, tiny.CHAT_LIMITS
+        family = tiny.dense_family()
+    else:
+        cfg, mix, limits = cfg or tiny.config(), tiny.mix(mixname), \
+            _limits(mixname)
+        family = None
+    cell = {"name": CELLS[mixname], "config": cfg["name"],
             "traffic": mixname, "chips": 1}
-    return run.run_cell(bench, cell, cfg or tiny.config(), tiny.mix(mixname),
-                        _limits(mixname), seed=seed, seconds=seconds,
-                        trace=False, peaks=spec.load_peaks("TPU v5 lite"),
+    return run.run_cell(bench, cell, cfg, mix, limits, seed=seed,
+                        seconds=seconds, trace=False,
+                        peaks=spec.load_peaks("TPU v5 lite"), family=family,
                         log=lambda m: None)
 
 
@@ -77,12 +86,13 @@ def test_control_is_not_correct():
     lies off the reference's in more elements than the limits allow."""
     import jax
     import model
+    import serve
     from repro.core.quantize import Q8Tensor
     cfg = copy.deepcopy(tiny.config())
     cfg["deployment"].update(weights="q8_0", cache_dtype="q8_0")
-    arch = model.arch_config(cfg)
-    import serve
-    params = model.make_weights(serve._build(arch), 3)
+    family = spec.family_module(cfg["family"])
+    params = model.make_weights(serve._build(family.arch_config(cfg)), 3,
+                                family.init_leaf)
     leaves = jax.tree.leaves(model.served(params, cfg["deployment"]),
                              is_leaf=lambda x: isinstance(x, Q8Tensor))
     assert any(isinstance(leaf, Q8Tensor) for leaf in leaves)
@@ -96,11 +106,11 @@ def test_control_is_not_correct():
 
 @pytest.mark.parametrize("tier", ["bf16", "q8_0"])
 def test_lane_planes_reads_the_pool(tier):
-    """``serve.lane_planes`` reads one lane's K/V back as float32: bf16
-    planes as stored, q8_0 planes decoded as the program decodes them."""
+    """Whisper's ``lane_planes`` (``serve.kv_planes``) reads one lane's
+    K/V back as float32: bf16 planes as stored, q8_0 planes decoded as
+    the program decodes them."""
     import jax
     import jax.numpy as jnp
-    import serve
     from repro.core.quantize import Q8Tensor, dequantize_q8_0
     from repro.models.attention import quantize_kv_cache
     rng = np.random.default_rng(0)
@@ -110,7 +120,7 @@ def test_lane_planes_reads_the_pool(tier):
              for kind, n in (("self", 16), ("cross", 24))}
     cache = {"layers": layer if tier == "bf16"
              else quantize_kv_cache(layer, tier)}
-    got = serve.lane_planes(cache, 1, 5, 7)
+    got = spec.family_module("whisper").lane_planes(cache, 1, 5, 7)
     for kind, n in (("self", 5), ("cross", 7)):
         for key in ("k", "v"):
             if tier == "bf16":

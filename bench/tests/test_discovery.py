@@ -1,6 +1,7 @@
 """A configuration, a mix, a metric and a kernel added as new files are
-found by name, with no edit to a file that is there; an unknown device
-is refused."""
+found by name, with no edit to a file that is there, and so is a
+configuration of another family with its family module; an unknown
+device is refused."""
 
 import json
 import shutil
@@ -8,6 +9,11 @@ import shutil
 import pytest
 
 import spec
+import tiny
+
+FAMILY_NAMES = ("arch_config", "init_leaf", "engine_options", "prompt",
+                "inputs", "submit", "lane_key", "read_lane", "Reference",
+                "request_flops")
 
 
 @pytest.fixture
@@ -19,7 +25,15 @@ def tree(tmp_path):
 
 
 def test_new_files_are_found(tree):
+    """Whisper's and the test-only dense family's new configurations,
+    with a family module, mixes, limits and a metric, all new files and
+    ``BENCHMARK.json`` entries, are found by name; the dense cell runs
+    ``correct`` through the harness on the CPU; no file of the copied
+    tree other than ``BENCHMARK.json`` changes."""
+    import run
     bench_dir = tree / "bench"
+    before = {p: p.read_bytes() for p in bench_dir.rglob("*")
+              if p.is_file()}
     cfg = spec.load_config(spec.load_benchmark(tree), "whisper-tiny.en",
                            root=tree)
     cfg["name"] = "whisper-new"
@@ -30,17 +44,32 @@ def test_new_files_are_found(tree):
         json.dumps({"requests": 4, "token_gap": 1.0}))
     (bench_dir / "metrics" / "new_metric.py").write_text(
         "def read(run):\n    return 42.0\n")
+    shutil.copy(tiny.HERE + "/families/dense.py",
+                bench_dir / "families" / "dense.py")
+    (bench_dir / "configs" / "dense-test.json").write_text(
+        json.dumps(tiny.DENSE))
+    (bench_dir / "mixes" / "chat.json").write_text(json.dumps(tiny.CHAT))
+    (bench_dir / "limits" / "dense.chat.json").write_text(
+        json.dumps(tiny.CHAT_LIMITS))
     bench = spec.load_benchmark(tree)
     bench["configs"].append({"name": "whisper-new", "source": "x",
                              "file": "bench/configs/whisper-new.json",
                              "reduced": [], "why": "x"})
+    bench["configs"].append({"name": "dense-test", "source": "x",
+                             "file": "bench/configs/dense-test.json",
+                             "reduced": [], "why": "x"})
     bench["workloads"].append({"name": "new.bursty", "config":
                                "whisper-new", "traffic": "bursty",
                                "chips": 1, "why": "x"})
+    bench["workloads"].append({"name": "dense.chat", "config": "dense-test",
+                               "traffic": "chat", "chips": 1, "why": "x"})
     bench["per_layer"].append({"name": "new_metric", "unit": "ms",
                                "better": "lower", "source": "host_clock",
                                "layer": "gateway", "moves": "ttft_p95_s",
                                "workloads": ["new.bursty"]})
+    for m in bench["end_to_end"]:
+        if m["name"] in ("ttft_p95_s", "e2e_p95_s"):
+            m["workloads"].append("dense.chat")
     (tree / "BENCHMARK.json").write_text(json.dumps(bench))
 
     bench = spec.load_benchmark(tree)
@@ -56,11 +85,32 @@ def test_new_files_are_found(tree):
     assert spec.metric_reader("new_metric", base=bench_dir).read(None) \
         == 42.0
 
+    cell = spec.find_cell(bench, "dense.chat")
+    cfg = spec.load_config(bench, cell["config"], root=tree)
+    family = spec.family_module(cfg["family"], base=bench_dir)
+    for name in FAMILY_NAMES:
+        assert hasattr(family, name), name
+    out = run.run_cell(bench, cell, cfg,
+                       spec.load_mix(cell["traffic"], base=bench_dir),
+                       spec.load_limits(cell["name"], base=bench_dir),
+                       seed=2**32 + 11, seconds=2.0, trace=False,
+                       peaks=spec.load_peaks("TPU v5 lite"), family=family,
+                       log=lambda m: None)
+    assert out["correct"], out["compared"]
+    assert set(out["compared"]) == {"token_gap", "short_outputs",
+                                    "self_kv_off"}
+    assert set(out["metrics"]) == {"ttft_p95_s", "e2e_p95_s", "setup_s"}
+    assert out["attempted"] > 0 and out["failed"] == 0
+    assert all(p.read_bytes() == b for p, b in before.items())
+
 
 def test_every_named_piece_exists():
     bench = spec.load_benchmark()
     for cell in bench["workloads"]:
-        spec.load_config(bench, cell["config"])
+        cfg = spec.load_config(bench, cell["config"])
+        family = spec.family_module(cfg["family"])
+        for name in FAMILY_NAMES:
+            assert hasattr(family, name), (cfg["family"], name)
         spec.load_mix(cell["traffic"])
         spec.load_limits(cell["name"])
         for m in spec.cell_metrics(bench, cell["name"], "per_layer"):
